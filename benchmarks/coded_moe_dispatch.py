@@ -14,15 +14,17 @@ dispatched activations, cutting dispatch bytes ~1/r at the price of r x Map
 Coding wins iff the saved dispatch time exceeds the added router/Map time -
 i.e. only in the dispatch-bound regime (small d_ff_expert / high top-k).
 """
-from repro.launch.mesh import ICI_BW, PEAK_FLOPS_BF16
+from repro.launch.roofline import peaks
+
+V5E = peaks("TPU v5 lite")
 
 
 def analyze(T, d, d_ff, E, topk, chips, r_values=(1, 2, 4)):
     rows = []
     for r in r_values:
-        t_disp = T * topk * d * 2 / r / (chips * ICI_BW)
-        t_expert = 3 * 2 * T * topk * d * d_ff / (chips * PEAK_FLOPS_BF16)
-        t_router = r * 2 * T * d * E / (chips * PEAK_FLOPS_BF16)
+        t_disp = T * topk * d * 2 / r / (chips * V5E.ici_bw)
+        t_expert = 3 * 2 * T * topk * d * d_ff / (chips * V5E.flops_bf16)
+        t_router = r * 2 * T * d * E / (chips * V5E.flops_bf16)
         rows.append((r, t_disp, t_expert, t_router,
                      t_disp + t_expert + t_router))
     return rows

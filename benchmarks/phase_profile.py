@@ -9,8 +9,10 @@ cross-checks that the summed exchange-span bits equal the run's
 `shuffle_bits`, and judges each phase's measured seconds + payload bytes
 against its bandwidth roof (`launch.roofline.phase_roofline`: HBM for the
 streaming phases, ICI for the exchange) - printing a %-of-roofline figure
-per phase. On CPU the fractions are methodology numbers (the roofs are the
-TPU v5e constants); on hardware the same spans produce the real figure.
+per phase. The roofs are the published peaks of the device the run found
+(`launch.roofline.PEAKS`, keyed by `device_kind`); off a TPU there is no
+device figure, and every share prints as "not measured". The phases are
+host spans: even on a TPU they time host NumPy around the device work.
 
 Outputs: per-phase report rows, the CI-gated ``scale_phase_profile_*``
 record (untraced replay wall-clock, so the gate measures the engine, not
@@ -30,7 +32,8 @@ from repro import graphs, obs
 from repro.core import algorithms as algo
 from repro.core import engine
 from repro.core.allocation import divisible_n, er_allocation
-from repro.launch.roofline import phase_roofline
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.roofline import PHASE_ROOFS, phase_roofline
 
 SMOKE = {"n": 360, "K": 4, "r": 2, "p": 0.05, "iters": 3}
 FULL = {"n": 2048, "K": 10, "r": 3, "p": 0.01, "iters": 10}
@@ -86,6 +89,9 @@ def profile(smoke: bool = False, trace_path: str | None = None) -> dict:
         raise AssertionError(
             f"span bits {span_bits} != run shuffle_bits {res.shuffle_bits}")
 
+    import jax
+
+    dev = jax.devices()[0]
     est = _phase_bytes_per_iter(sess.plan, g)
     phases = {}
     for ph in PHASES:
@@ -93,10 +99,12 @@ def profile(smoke: bool = False, trace_path: str | None = None) -> dict:
         secs = sum(s.duration_s for s in spans)
         byts = (span_bits / 8 if ph == "exchange"
                 else est[ph] * len(spans))
-        rl = phase_roofline(ph, secs, byts, chips=cfg["K"])
+        frac = (phase_roofline(ph, secs, byts, device_kind=dev.device_kind,
+                               chips=cfg["K"]).fraction
+                if dev.platform == "tpu" else None)
         phases[ph] = {"count": len(spans), "seconds": secs,
-                      "bytes": byts, "roof": rl.roof,
-                      "roofline_fraction": rl.fraction}
+                      "bytes": byts, "roof": PHASE_ROOFS[ph],
+                      "roofline_fraction": frac}
 
     if trace_path:
         tracer.dump_chrome_trace(trace_path)
@@ -110,10 +118,14 @@ def profile(smoke: bool = False, trace_path: str | None = None) -> dict:
             "trace_path": trace_path}
 
 
+def _share(p: dict) -> str:
+    f = p["roofline_fraction"]
+    return "not measured" if f is None else f"{100 * f:.4f}%"
+
+
 def _fractions_str(phases: dict) -> str:
-    return " ".join(
-        f"{ph}:{100 * p['roofline_fraction']:.4f}%({p['roof']})"
-        for ph, p in phases.items())
+    return " ".join(f"{ph}:{_share(p)}({p['roof']})"
+                    for ph, p in phases.items())
 
 
 def run(report, smoke: bool = False, trace_path: str | None = None) -> dict:
@@ -124,7 +136,7 @@ def run(report, smoke: bool = False, trace_path: str | None = None) -> dict:
                p["seconds"] / max(p["count"], 1) * 1e6,
                f"bytes_per_iter={p['bytes'] / max(p['count'], 1):.0f} "
                f"roof={p['roof']} "
-               f"roofline={100 * p['roofline_fraction']:.4f}%")
+               f"roofline={_share(p)}")
     total = sum(p["seconds"] for p in phases.values())
     report(f"scale_phase_profile_n{prof['n']}",
            prof["untraced_s_per_iter"] * 1e6,
@@ -142,6 +154,7 @@ def main(argv=None) -> None:
     ap.add_argument("--trace", default=None, metavar="PATH",
                     help="write a Chrome-trace/perfetto JSON artifact")
     args = ap.parse_args(argv)
+    use_compile_cache()
     print("name,us_per_call,derived")
     prof = run(lambda name, us, derived="": print(f"{name},{us:.1f},{derived}"),
                smoke=args.smoke, trace_path=args.trace)
@@ -151,7 +164,7 @@ def main(argv=None) -> None:
     for ph, p in prof["phases"].items():
         print(f"  {ph:<{ws}}  {p['seconds'] * 1e3:8.2f} ms  "
               f"{p['bytes'] / 1e6:9.3f} MB  vs {p['roof'].upper()} roof: "
-              f"{100 * p['roofline_fraction']:.4f}% of roofline")
+              f"{_share(p)} of roofline")
     if args.trace:
         print(f"\ntrace written to {args.trace} "
               "(load in chrome://tracing or ui.perfetto.dev)")

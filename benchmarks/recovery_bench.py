@@ -101,6 +101,9 @@ def run(report, smoke=False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     smoke = "--smoke" in sys.argv[1:]
 
     def _report(name, us, derived=""):

@@ -48,6 +48,9 @@ def main(argv: list[str] | None = None) -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write records as JSON to PATH")
     args = ap.parse_args(argv)
+    mods = _modules()              # puts src/ on the path in script mode
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     records: list[dict] = []
     if args.json:                  # fail fast on an unwritable path
@@ -60,7 +63,7 @@ def main(argv: list[str] | None = None) -> None:
         print(f"{name},{us:.1f},{derived}", flush=True)
         records.append({"name": name, "us_per_call": us, "derived": derived})
 
-    for mod in _modules():
+    for mod in mods:
         kwargs = {}
         if args.smoke and "smoke" in inspect.signature(mod.run).parameters:
             kwargs["smoke"] = True

@@ -178,7 +178,8 @@ def _reduce_sparse(program: VertexProgram, g: Graph, edge_vals: np.ndarray,
 
 
 def _reduce_spmv(program: VertexProgram, g: Graph, state: np.ndarray, *,
-                 bm: int = 128, interpret: bool = True) -> np.ndarray:
+                 bm: int = 128,
+                 interpret: bool | None = None) -> np.ndarray:
     """Blocked row reduction through the kernels/spmv Pallas kernel.
 
     Valid for linear programs (v_{i,j} = map_source(g, state)[j], Reduce =
@@ -679,9 +680,10 @@ def run(program: VertexProgram, g: Graph, alloc: Allocation | None,
     `path` picks the execution form (see module docstring); "auto" resolves
     to sparse whenever the program supplies the edge-value form. `backend`
     ("numpy" | "spmv" | "fused") selects the sparse implementation;
-    `backend_opts` is forwarded to it (spmv: `bm`, `interpret` - pass
-    ``{"interpret": False}`` on real TPU hardware; fused: `mesh`, `encode`,
-    `interpret` - see `fused_shuffle.FusedSparseShuffle`). Unknown option
+    `backend_opts` is forwarded to it (spmv: `bm`, `interpret`; fused:
+    `mesh`, `encode`, `interpret` - see `fused_shuffle.FusedSparseShuffle`).
+    Pallas kernels run compiled on a TPU and interpreted on the CPU unless
+    `interpret` says otherwise (`kernels.platform`). Unknown option
     keys raise `ValueError` naming the accepted set. Prefer `compile` when
     running the same (graph, allocation) more than once.
     """

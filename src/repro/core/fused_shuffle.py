@@ -58,11 +58,11 @@ import dataclasses
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..kernels.platform import interpret_mode
 from ..kernels.xor_code import ops as xor_ops
-from ..launch.mesh import (Topology, make_racks_mesh, make_servers_mesh,
-                           shard_map_compat)
+from ..launch.mesh import Topology, make_racks_mesh, make_servers_mesh
 from ..obs import get_tracer
 from ..obs.metrics import get_registry
 from .allocation import Allocation
@@ -494,6 +494,74 @@ def partition_hierarchical(hplan: HierarchicalPlan, csr: CSR,
 ENCODE_BACKENDS = ("xor-ref", "xor-kernel", "jnp")
 
 
+def _fold(a, axis: int, op):
+    """uint32 XOR/OR reduction of one (small, static) axis."""
+    return jax.lax.reduce(a, jnp.uint32(0), op, (axis % a.ndim,))
+
+
+def _gather_servers(words, axis_name: str):
+    """all_gather of per-shard [(B,) N] words -> [(B,) servers, N].
+
+    The gather runs on a new leading axis: gathering along a minor axis
+    makes XLA move the payload axis B onto the TPU's lanes, where it is
+    padded to 128 (16x at B=8).
+    """
+    return jnp.moveaxis(jax.lax.all_gather(words, axis_name), 0, -2)
+
+
+def _pad_last(a):
+    """Append one zero column on the last axis (the sentinel column)."""
+    return jnp.pad(a, ((0, 0),) * (a.ndim - 1) + ((0, 1),))
+
+
+def take(words, *idx):
+    """``words[..., *idx]`` with at most one leading payload axis, one
+    payload row at a time.
+
+    A single gather over [B, N] words lets XLA lay the operand out with the
+    B axis minor (each gathered slice a contiguous B-vector), which a TPU
+    pads to 128 lanes; a loop of 1-D gathers keeps N on the lanes.
+    """
+    if words.ndim == len(idx):
+        return words[idx]
+    return jax.lax.map(lambda row: row[idx], words)
+
+
+def lanes_last(a: np.ndarray) -> np.ndarray:
+    """Device layout of a partition table: [rows, W or Dmax, slots...] ->
+    [rows, slots..., W or Dmax].
+
+    On a TPU the last axis is the 128-lane axis, and a trailing slot axis
+    of r (or r-1) words would be padded to 128 lanes - a 64x blow-up that
+    does not fit the chip at deployment size.
+    """
+    return np.ascontiguousarray(np.moveaxis(a, 1, -1))
+
+
+def shard_encoder(encode: str, interpret: bool):
+    """The exchange's per-shard encode for one `encode` backend.
+
+    loc [(B,) L+1] uint32 local words (last entry 0 = sentinel); idx, shift,
+    mask [r, W], one server's `lanes_last` encode tables -> [(B,) W] coded
+    columns. "xor-ref" and "xor-kernel" fold the slots through
+    `xor_ops.xor_encode_rows` (jnp oracle / Pallas kernel), "jnp" through
+    a plain XOR reduce; all three give the same words.
+    """
+    if encode not in ENCODE_BACKENDS:
+        raise ValueError(f"unknown encode backend {encode!r}")
+
+    def enc(loc, idx, shift, mask):
+        slotw = (take(loc, idx) << shift) & mask            # [(B,) r, W]
+        if encode == "jnp":
+            return _fold(slotw, -2, jax.lax.bitwise_xor)
+        rows = jnp.moveaxis(slotw, -2, 0)                   # [r, (B,) W]
+        out = xor_ops.xor_encode_rows(
+            rows.reshape(rows.shape[0], -1),
+            use_kernel=encode == "xor-kernel", interpret=interpret)
+        return out.reshape(rows.shape[1:])
+    return enc
+
+
 class FusedSparseShuffle:
     """Jit-once / replay-every-iteration multi-device coded Shuffle.
 
@@ -510,15 +578,17 @@ class FusedSparseShuffle:
 
     encode:
       "xor-ref"    - batched kernels/xor_code route, jnp oracle (default).
-      "xor-kernel" - same route through the Pallas kernel (interpret=True
-                     off-TPU; pass interpret=False on real hardware).
+      "xor-kernel" - same route through the Pallas kernel: compiled when
+                     the mesh's devices are TPUs, interpreted on CPU devices
+                     (`kernels.platform.interpret_mode`; `interpret=`
+                     overrides).
       "jnp"        - plain jnp XOR reduce (no kernel route).
     """
 
     def __init__(self, plan: ShufflePlan | HierarchicalPlan, csr: CSR,
                  alloc: Allocation, mesh: Mesh | None = None, *,
                  topology: Topology | None = None, encode: str = "xor-ref",
-                 interpret: bool = True):
+                 interpret: bool | None = None):
         if encode not in ENCODE_BACKENDS:
             raise ValueError(f"unknown encode backend {encode!r}")
         self._bind(plan, csr, alloc, topology)
@@ -531,11 +601,13 @@ class FusedSparseShuffle:
                 f"mesh has {self.mesh.devices.size} devices but the plan "
                 f"has K={self.plan.K} servers (one device per server)")
         self._encode = encode
-        self._interpret = interpret
+        self._interpret = interpret_mode(
+            interpret, self.mesh.devices.flat[0].platform)
         build = self._build_hier if self._hier else self._build
-        self._fn = build(encode, interpret, batched=False)
-        self._fn_batched = None       # built lazily on the first [nnz, B] call
-        self._dev_tables = self._make_dev_tables()
+        # One jitted exchange serves [nnz] and [nnz, B] payloads alike: jit
+        # traces each payload shape once and replays it.
+        self._fn = build(encode, self._interpret)
+        self._dev_tables = None               # placed on the first exchange
 
     def _bind(self, plan, csr, alloc, topology) -> None:
         """Resolve (plan, topology) into the flat or two-level partition.
@@ -570,7 +642,28 @@ class FusedSparseShuffle:
             self.sched = partition_plan(plan, csr, alloc)
             self._schedule_bits = plan.coded_bits + plan.leftover_bits
 
-    def _make_dev_tables(self):
+    def _in_specs(self) -> tuple:
+        """The exchange's shard_map in_specs: loc, then the device tables.
+
+        Flat: every array is split one server per device. Two levels: loc
+        and the per-server tables split over (racks, servers); the rack
+        encode tables split over racks, replicated within a rack.
+        """
+        if self._hier:
+            return ((P("racks", "servers"),) + (P("racks"),) * 3
+                    + (P("racks", "servers"),) * 9)
+        return (P("servers"),) * 11
+
+    def _place(self, arrays, specs) -> tuple:
+        """Host arrays -> device arrays laid out as the exchange reads them:
+        each device receives only its own block (nothing is staged whole
+        on one device and resharded per call)."""
+        return tuple(jax.device_put(a, NamedSharding(self.mesh, p))
+                     for a, p in zip(arrays, specs))
+
+    def _host_tables(self) -> tuple:
+        """The partition tables as the exchange takes them (`lanes_last`,
+        mesh-shaped), still on the host."""
         s = self.sched
         if self._hier:
             R, S = self.topology.racks, self.topology.servers_per_rack
@@ -579,12 +672,14 @@ class FusedSparseShuffle:
                 # per-server rows -> mesh-shaped (racks, servers) blocks
                 return a.reshape((R, S) + a.shape[1:])
 
-            return tuple(jnp.asarray(a) for a in (
-                s.enc_l, s.enc_shift, s.enc_mask,
-                rs(s.dec_rk), rs(s.dec_w), rs(s.dec_mask), rs(s.dec_shift),
-                rs(s.strip_f), rs(s.strip_shift), rs(s.strip_mask),
-                rs(s.direct_l), rs(s.direct_mask)))
-        return tuple(jnp.asarray(a) for a in (
+            return (
+                lanes_last(s.enc_l), lanes_last(s.enc_shift),
+                lanes_last(s.enc_mask),
+                *(rs(lanes_last(a)) for a in (
+                    s.dec_rk, s.dec_w, s.dec_mask, s.dec_shift, s.strip_f,
+                    s.strip_shift, s.strip_mask)),
+                rs(s.direct_l), rs(s.direct_mask))
+        return tuple(lanes_last(a) for a in (
             s.enc_l, s.enc_shift, s.enc_mask, s.dec_s, s.dec_w, s.dec_mask,
             s.dec_shift, s.strip_l, s.strip_shift, s.strip_mask))
 
@@ -611,109 +706,73 @@ class FusedSparseShuffle:
         ex._encode = self._encode
         ex._interpret = self._interpret
         ex._fn = self._fn
-        ex._fn_batched = self._fn_batched
-        ex._dev_tables = ex._make_dev_tables()
+        ex._dev_tables = None
         return ex
 
-    def _build(self, encode: str, interpret: bool, batched: bool):
-        use_kernel = encode == "xor-kernel"
-        # Batched payloads append one trailing B axis to every *word* array
-        # (loc, buffers, deliveries); the schedule tables are value-agnostic
-        # and broadcast behind it. All device ops stay uint32 shift/mask/XOR,
-        # so payload column b is bitwise the unbatched exchange of column b.
-        bx = (lambda a: a[..., None]) if batched else (lambda a: a)
+    def _build(self, encode: str, interpret: bool):
+        # Batched payloads put one B axis *before* every word array's long
+        # axis (loc [B, Lmax+1], buffers [B, K, W], deliveries [B, Dmax]);
+        # the schedule tables are value-agnostic and broadcast against it.
+        # All device ops stay uint32 shift/mask/XOR, so payload column b is
+        # bitwise the unbatched exchange of column b.
+        encode_shard = shard_encoder(encode, interpret)
 
         def per_server(loc, enc_l, enc_shift, enc_mask, dec_s, dec_w,
                        dec_mask, dec_shift, strip_l, strip_shift, strip_mask):
-            loc = loc[0]                          # [Lmax+1] (or [Lmax+1, B])
-            if encode == "jnp":
-                slotw = (loc[enc_l[0]] << bx(enc_shift[0])) & bx(enc_mask[0])
-                coded = jax.lax.reduce(slotw, jnp.uint32(0),
-                                       jax.lax.bitwise_xor, (1,))
-            else:
-                coded = xor_ops.xor_encode_slots(
-                    loc, enc_l[0], enc_shift[0], enc_mask[0],
-                    use_kernel=use_kernel, interpret=interpret)
-            allbufs = jax.lax.all_gather(coded, "servers")  # [K, W(, B)]
-            pad = ((0, 0), (0, 1)) + (((0, 0),) if batched else ())
-            allbufs = jnp.pad(allbufs, pad)                 # zero col W
-            got = allbufs[dec_s[0], dec_w[0]]               # [Dmax, r(, B)]
-            sw = (loc[strip_l[0]] << bx(strip_shift[0])) & bx(strip_mask[0])
-            strip = jax.lax.reduce(sw, jnp.uint32(0),
-                                   jax.lax.bitwise_xor, (2,))
-            rec = ((got ^ strip) & bx(dec_mask[0])) >> bx(dec_shift[0])
-            words = jax.lax.reduce(rec, jnp.uint32(0),
-                                   jax.lax.bitwise_or, (1,))
-            return words[None]                              # [1, Dmax(, B)]
+            loc = loc[0]                          # [(B,) Lmax+1]
+            coded = encode_shard(loc, enc_l[0], enc_shift[0], enc_mask[0])
+            allbufs = _gather_servers(coded, "servers")     # [(B,) K, W]
+            allbufs = _pad_last(allbufs)                    # zero col W
+            got = take(allbufs, dec_s[0], dec_w[0])         # [(B,) r, Dmax]
+            sw = (take(loc, strip_l[0]) << strip_shift[0]) & strip_mask[0]
+            strip = _fold(sw, -2, jax.lax.bitwise_xor)      # [(B,) r, Dmax]
+            rec = ((got ^ strip) & dec_mask[0]) >> dec_shift[0]
+            return _fold(rec, -2, jax.lax.bitwise_or)[None]  # [1, (B,) Dmax]
 
         # pallas_call has no replication rule, so the kernel route must
         # disable the output-replication checker (outputs are per-shard
         # anyway - nothing is claimed replicated).
-        f = shard_map_compat(per_server, mesh=self.mesh,
-                             in_specs=(P("servers"),) * 11,
-                             out_specs=P("servers"), check=not use_kernel)
+        f = jax.shard_map(per_server, mesh=self.mesh,
+                          in_specs=self._in_specs(),
+                          out_specs=P("servers"),
+                          check_vma=encode != "xor-kernel")
         return jax.jit(f)
 
-    def _build_hier(self, encode: str, interpret: bool, batched: bool):
-        use_kernel = encode == "xor-kernel"
-        bx = (lambda a: a[..., None]) if batched else (lambda a: a)
-
-        def fold(a, axis, op):
-            # static unroll over a tiny (<= rr) axis: jax.lax.reduce has no
-            # replication rule on a two-axis mesh in jax 0.4.x, plain
-            # binary xor/or ops do
-            parts = [jax.lax.index_in_dim(a, t, axis, keepdims=False)
-                     for t in range(a.shape[axis])]
-            out = parts[0]
-            for x in parts[1:]:
-                out = op(out, x)
-            return out
+    def _build_hier(self, encode: str, interpret: bool):
+        encode_shard = shard_encoder(encode, interpret)
 
         def per_server(loc, enc_l, enc_shift, enc_mask, dec_rk, dec_w,
                        dec_mask, dec_shift, strip_f, strip_shift,
                        strip_mask, direct_l, direct_mask):
-            loc = loc[0, 0]                       # [Lmax+1(, B)]
+            loc = loc[0, 0]                       # [(B,) Lmax+1]
             # Phase A: plain all_gather of local Map words on the cheap
-            # intra-rack axis -> the rack's union buffer, on every member.
-            rloc = jax.lax.all_gather(loc, "servers")   # [S, Lmax+1(, B)]
-            rflat = rloc.reshape((-1,) + rloc.shape[2:])
+            # intra-rack axis -> the rack's union buffer, on every member
+            # (block s of rflat = server s of the rack).
+            rloc = _gather_servers(loc, "servers")  # [(B,) S, Lmax+1]
+            rflat = rloc.reshape(loc.shape[:-1] + (-1,))  # [(B,) S(Lmax+1)]
             # Phase B: rack-level coded encode (replicated within the rack:
             # every member computes the same buffer from rflat - recompute
             # beats a leader branch) + one coded XOR all_gather on the
             # expensive inter-rack axis.
-            el, esh, emk = enc_l[0], enc_shift[0], enc_mask[0]
-            if encode == "jnp":
-                slotw = (rflat[el] << bx(esh)) & bx(emk)
-                coded = fold(slotw, 1, jnp.bitwise_xor)
-            else:
-                coded = xor_ops.xor_encode_slots(
-                    rflat, el, esh, emk, use_kernel=use_kernel,
-                    interpret=interpret)
-            allbufs = jax.lax.all_gather(coded, "racks")    # [R, Wx(, B)]
-            # zero col Wx appended via concatenate (not jnp.pad: the pad
-            # scalar defeats the 0.4.x two-axis replication checker)
-            allbufs = jnp.concatenate(
-                [allbufs, jnp.zeros_like(allbufs[:, :1])], axis=1)
-            got = allbufs[dec_rk[0, 0], dec_w[0, 0]]        # [Dmax, rr(, B)]
-            if strip_f.shape[-1]:                           # rr > 1
-                sw = ((rflat[strip_f[0, 0]] << bx(strip_shift[0, 0]))
-                      & bx(strip_mask[0, 0]))
-                strip = fold(sw, 2, jnp.bitwise_xor)
-            else:
-                strip = jnp.zeros_like(got)
-            rec = (((got ^ strip) & bx(dec_mask[0, 0]))
-                   >> bx(dec_shift[0, 0]))
-            words = fold(rec, 1, jnp.bitwise_or)
+            coded = encode_shard(rflat, enc_l[0], enc_shift[0], enc_mask[0])
+            allbufs = _gather_servers(coded, "racks")       # [(B,) R, Wx]
+            allbufs = _pad_last(allbufs)                    # zero col Wx
+            got = take(allbufs, dec_rk[0, 0], dec_w[0, 0])  # [(B,) rr, Dmax]
+            sw = ((take(rflat, strip_f[0, 0]) << strip_shift[0, 0])
+                  & strip_mask[0, 0])
+            strip = _fold(sw, -2, jax.lax.bitwise_xor)
+            rec = ((got ^ strip) & dec_mask[0, 0]) >> dec_shift[0, 0]
+            words = _fold(rec, -2, jax.lax.bitwise_or)      # [(B,) Dmax]
             # Intra-only deliveries never crossed a rack: direct gather
             # from the phase-A buffer (mask 0 on inter deliveries).
-            words = words | (rflat[direct_l[0, 0]] & bx(direct_mask[0, 0]))
-            return words[None, None]              # [1, 1, Dmax(, B)]
+            words = words | (take(rflat, direct_l[0, 0]) & direct_mask[0, 0])
+            return words[None, None]              # [1, 1, (B,) Dmax]
 
-        f = shard_map_compat(
+        f = jax.shard_map(
             per_server, mesh=self.mesh,
-            in_specs=(P("racks", "servers"),) + (P("racks"),) * 3
-                     + (P("racks", "servers"),) * 9,
-            out_specs=P("racks", "servers"), check=not use_kernel)
+            in_specs=self._in_specs(),
+            out_specs=P("racks", "servers"),
+            check_vma=encode != "xor-kernel")
         return jax.jit(f)
 
     def exchange_words(self, edge_words: np.ndarray) -> np.ndarray:
@@ -729,6 +788,26 @@ class FusedSparseShuffle:
         schedule tables are shared), column-b bitwise equal to the
         unbatched exchange of that column.
         """
+        dev = self.exchange_device(edge_words)
+        plan = self.plan
+        B = int(edge_words.shape[1]) if np.ndim(edge_words) == 2 else 1
+        with get_tracer().span("phase.decode", backend="fused", B=B,
+                               deliveries=int(plan.all_k.size)):
+            out = np.asarray(dev)                 # [K, (B,) Dmax]
+            if self._hier:
+                out = out.reshape((plan.K,) + out.shape[2:])
+            M = plan.all_k.size
+            # [M] or, batched, [M, B] (the sliced B axis sits between the
+            # two index arrays, so numpy puts it last)
+            return out[plan.all_k, ..., np.arange(M, dtype=np.int64)
+                       - plan.ptr[plan.all_k]]
+
+    def exchange_device(self, edge_words: np.ndarray) -> jax.Array:
+        """Encode + exchange of `exchange_words`, left on the mesh.
+
+        Returns the per-server padded delivery words, sharded one server
+        per device: [K, (B,) Dmax] flat, [R, S, (B,) Dmax] on two levels.
+        """
         s = self.sched
         tr = get_tracer()
         ew = np.ascontiguousarray(edge_words, np.uint32)
@@ -736,28 +815,19 @@ class FusedSparseShuffle:
         B = int(ew.shape[1]) if batched else 1
         with tr.span("phase.encode", backend="fused", B=B,
                      nnz=int(edge_words.shape[0])):
-            if batched:
-                if self._fn_batched is None:
-                    build = self._build_hier if self._hier else self._build
-                    self._fn_batched = build(self._encode, self._interpret,
-                                             batched=True)
-                ew = np.concatenate(
-                    [ew, np.zeros((1, ew.shape[1]), np.uint32)], axis=0)
-                loc = np.zeros((s.K, s.Lmax + 1, ew.shape[1]),
-                               dtype=np.uint32)
-                fn = self._fn_batched
-            else:
-                ew = np.append(ew, np.uint32(0))
-                loc = np.zeros((s.K, s.Lmax + 1), dtype=np.uint32)
-                fn = self._fn
-            loc[:, :s.Lmax] = ew[s.loc_e]
+            # payload axis first, the sentinel zero word at index nnz
+            ew = np.concatenate([np.atleast_2d(ew.T),
+                                 np.zeros((B, 1), np.uint32)], axis=1)
+            loc = np.zeros((s.K, B, s.Lmax + 1), dtype=np.uint32)
+            loc[:, :, :s.Lmax] = np.moveaxis(ew[:, s.loc_e], 0, 1)
+            if not batched:
+                loc = loc[:, 0]                   # [K, Lmax+1]
             if self._hier:
                 # device (rho, s) of the (racks, servers) mesh is server
                 # rho * S + s, so the reshape is the identity placement
                 loc = loc.reshape((self.topology.racks,
                                    self.topology.servers_per_rack)
                                   + loc.shape[1:])
-        plan = self.plan
         bits = self._schedule_bits * B
         attrs = dict(backend="fused", bits=bits, B=B, K=s.K)
         if self._hier:
@@ -767,7 +837,11 @@ class FusedSparseShuffle:
         # on the device buffers before stamping so the span covers the
         # collective's execution, not just its dispatch.
         with tr.span("phase.exchange", **attrs):
-            dev = fn(jnp.asarray(loc), *self._dev_tables)
+            if self._dev_tables is None:
+                self._dev_tables = self._place(self._host_tables(),
+                                               self._in_specs()[1:])
+            (loc,) = self._place((loc,), self._in_specs()[:1])
+            dev = self._fn(loc, *self._dev_tables)
             jax.block_until_ready(dev)
         if self._hier:
             reg = get_registry()
@@ -777,14 +851,7 @@ class FusedSparseShuffle:
             reg.counter("shuffle_intra_rack_bits_total",
                         "coded-Shuffle bits moving inside racks") \
                 .inc(self.hplan.intra_rack_bits * B)
-        with tr.span("phase.decode", backend="fused", B=B,
-                     deliveries=int(plan.all_k.size)):
-            out = np.asarray(dev)
-            if self._hier:
-                out = out.reshape((plan.K,) + out.shape[2:])
-            M = plan.all_k.size
-            return out[plan.all_k, np.arange(M, dtype=np.int64)
-                       - plan.ptr[plan.all_k]]
+        return dev
 
     def execute(self, edge_vals: np.ndarray) -> PlanShuffleResult:
         """Drop-in peer of `ShufflePlan.execute_coded_sparse` (batched
@@ -801,7 +868,7 @@ class FusedSparseShuffle:
 
 def run_fused_sparse(g: Graph, edge_vals: np.ndarray, alloc: Allocation,
                      mesh: Mesh | None = None, *, encode: str = "xor-ref",
-                     interpret: bool = True) -> PlanShuffleResult:
+                     interpret: bool | None = None) -> PlanShuffleResult:
     """Convenience one-shot: compile + partition + one sparse exchange."""
     plan = compile_plan_csr(g.csr, alloc, validate=False)
     ex = FusedSparseShuffle(plan, g.csr, alloc, mesh, encode=encode,
@@ -895,9 +962,7 @@ def fused_exchange(values: jnp.ndarray, enc_idx, dec_src, dec_tgt, dec_strip,
     missing values (0 where not delivered) - identical on every server.
     Validation reference only: the production path is `FusedSparseShuffle`.
     """
-    words = _as_words(values)
-
-    def per_server(enc_s, dec_src_s, dec_tgt_s, dec_strip_s):
+    def per_server(words, enc_s, dec_src_s, dec_tgt_s, dec_strip_s):
         # enc_s [1, W, r, 2] on this shard.
         enc_s = enc_s[0]
         valid = enc_s[:, :, 0] >= 0
@@ -921,12 +986,15 @@ def fused_exchange(values: jnp.ndarray, enc_idx, dec_src, dec_tgt, dec_strip,
             jnp.where(tgt_ok, rec, jnp.uint32(0)))
         return jax.lax.psum(out, "servers")   # union of per-server recoveries
 
-    f = shard_map_compat(per_server, mesh=mesh,
-                         in_specs=(P("servers"), P("servers"), P("servers"),
-                                   P("servers")),
-                         out_specs=P())
-    out_words = f(jnp.asarray(enc_idx), jnp.asarray(dec_src),
-                  jnp.asarray(dec_tgt), jnp.asarray(dec_strip))
+    # The value words enter as a replicated argument, not a closure, and
+    # the call runs under the mesh: a mesh with explicit axes (the
+    # `jax.make_mesh` default) refuses arrays committed to one device.
+    f = jax.shard_map(per_server, mesh=mesh,
+                      in_specs=(P(),) + (P("servers"),) * 4, out_specs=P())
+    with jax.set_mesh(mesh):
+        out_words = jax.jit(f)(_as_words(values), jnp.asarray(enc_idx),
+                               jnp.asarray(dec_src), jnp.asarray(dec_tgt),
+                               jnp.asarray(dec_strip))
     return _as_floats(out_words)
 
 

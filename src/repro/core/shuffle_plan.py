@@ -241,7 +241,7 @@ class ShufflePlan:
                 & self.slot_mask[..., None])
 
     def execute_coded(self, values: np.ndarray, *, backend: str = "numpy",
-                      interpret: bool = True) -> PlanShuffleResult:
+                      interpret: bool | None = None) -> PlanShuffleResult:
         """One bit-exact coded Shuffle (multicast groups + unicast leftovers).
 
         backend:
@@ -256,7 +256,7 @@ class ShufflePlan:
 
     def _coded_result(self, pair_vals: np.ndarray, left_vals: np.ndarray, *,
                       backend: str = "numpy",
-                      interpret: bool = True) -> PlanShuffleResult:
+                      interpret: bool | None = None) -> PlanShuffleResult:
         """Coded encode/decode from already-gathered scheduled values.
 
         Batched pair_vals [P, B] / left_vals [L, B] ride the identical
@@ -383,7 +383,7 @@ class ShufflePlan:
     def execute_coded_sparse(self, edge_vals: np.ndarray,
                              tables: PlanEdgeTables, *,
                              backend: str = "numpy",
-                             interpret: bool = True) -> PlanShuffleResult:
+                             interpret: bool | None = None) -> PlanShuffleResult:
         """Coded Shuffle from a [nnz] edge-value vector; bit-exact against
         `execute_coded` on the dense scatter of the same values. Batched
         edge_vals [nnz, B] carry B query payloads through the one schedule
@@ -1484,7 +1484,7 @@ class HierarchicalPlan:
     def execute_coded_sparse(self, edge_vals: np.ndarray,
                              tables: HierarchicalEdgeTables, *,
                              backend: str = "numpy",
-                             interpret: bool = True) -> PlanShuffleResult:
+                             interpret: bool | None = None) -> PlanShuffleResult:
         """Two-level coded Shuffle from a [nnz] edge-value vector.
 
         Delivered `values` are bitwise equal to the flat plan's

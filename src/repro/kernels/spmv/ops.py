@@ -19,7 +19,8 @@ def _pad_to(x: jnp.ndarray, mult: int, axis: int) -> jnp.ndarray:
 
 
 def spmv(adj: jnp.ndarray, x: jnp.ndarray, *, bm: int = 128, bk: int = 128,
-         use_kernel: bool = True, interpret: bool = True) -> jnp.ndarray:
+         use_kernel: bool = True,
+         interpret: bool | None = None) -> jnp.ndarray:
     """y = adj @ x, padding ragged shapes up to the tile grid."""
     if not use_kernel:
         return spmv_ref(adj, x)
@@ -39,7 +40,7 @@ def pagerank_step(adj: jnp.ndarray, rank: jnp.ndarray, damping: float = 0.15,
 def spmv_csr_rows(indptr: np.ndarray, indices: np.ndarray, c: np.ndarray,
                   n: int, *, rows: np.ndarray | None = None, bm: int = 128,
                   bk: int = 128, use_kernel: bool = True,
-                  interpret: bool = True) -> np.ndarray:
+                  interpret: bool | None = None) -> np.ndarray:
     """acc[i] = sum_{j in row i} c[j] from a CSR adjacency, via the Pallas
     kernel in blocked [bm, n] row strips.
 

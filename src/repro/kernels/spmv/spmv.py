@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import interpret_mode
+
 
 def _spmv_kernel(a_ref, x_ref, o_ref):
     @pl.when(pl.program_id(1) == 0)
@@ -28,7 +30,7 @@ def _spmv_kernel(a_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("bm", "bk", "interpret"))
 def spmv_pallas(adj: jnp.ndarray, x: jnp.ndarray, *, bm: int = 128,
-                bk: int = 128, interpret: bool = True) -> jnp.ndarray:
+                bk: int = 128, interpret: bool | None = None) -> jnp.ndarray:
     """y = adj @ x via pallas_call. Shapes must tile evenly by (bm, bk)."""
     m, n = adj.shape
     assert m % bm == 0 and n % bk == 0, (m, n, bm, bk)
@@ -42,6 +44,6 @@ def spmv_pallas(adj: jnp.ndarray, x: jnp.ndarray, *, bm: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(adj, x2)
     return out.reshape(m)

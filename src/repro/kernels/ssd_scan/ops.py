@@ -14,7 +14,8 @@ from .ssd_scan import ssd_chunk_pallas
 @functools.partial(jax.jit, static_argnames=("chunk", "use_kernel", "interpret"))
 def ssd(x: jnp.ndarray, dt: jnp.ndarray, A: jnp.ndarray, B: jnp.ndarray,
         C: jnp.ndarray, D: jnp.ndarray, h0: jnp.ndarray | None = None, *,
-        chunk: int = 64, use_kernel: bool = True, interpret: bool = True
+        chunk: int = 64, use_kernel: bool = True,
+        interpret: bool | None = None
         ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Batched SSD. x [G, L, P]; dt [G, L]; A [G]; B/C [G, L, N]; D [G].
 

@@ -22,6 +22,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ..platform import interpret_mode
+
 
 def _ssd_chunk_kernel(x_ref, dt_ref, dta_ref, b_ref, c_ref,
                       y_ref, s_ref, g_ref, cexp_ref):
@@ -52,7 +54,7 @@ def _ssd_chunk_kernel(x_ref, dt_ref, dta_ref, b_ref, c_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk_pallas(x, dt, dta, b, c, *, interpret: bool = True):
+def ssd_chunk_pallas(x, dt, dta, b, c, *, interpret: bool | None = None):
     """x [G, Ch, Q, P]; dt/dta [G, Ch, Q]; b/c [G, Ch, Q, N].
 
     -> y_intra [G, Ch, Q, P], S [G, Ch, N, P], Gdecay [G, Ch], Cexp [G, Ch, Q, N]
@@ -72,5 +74,5 @@ def ssd_chunk_pallas(x, dt, dta, b, c, *, interpret: bool = True):
                    jax.ShapeDtypeStruct((g, ch, n, p), jnp.float32),
                    jax.ShapeDtypeStruct((g, ch), jnp.float32),
                    jax.ShapeDtypeStruct((g, ch, q, n), jnp.float32)),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, dta, b, c)

@@ -1,4 +1,8 @@
-"""Jitted public wrappers for XOR encode/decode (fused TPU shuffle path)."""
+"""Jitted public wrappers for XOR encode/decode (fused TPU shuffle path).
+
+`interpret=None` everywhere below: the platform decides
+(`kernels.platform.interpret_mode`).
+"""
 from __future__ import annotations
 
 import jax
@@ -8,33 +12,54 @@ from . import ref
 from .xor_code import xor_encode_pallas
 
 
-def xor_encode(rows: jnp.ndarray, valid: jnp.ndarray, *, use_kernel: bool = True,
-               interpret: bool = True) -> jnp.ndarray:
+def xor_encode(rows: jnp.ndarray, valid: jnp.ndarray | None, *,
+               use_kernel: bool = True,
+               interpret: bool | None = None) -> jnp.ndarray:
     if use_kernel:
         return xor_encode_pallas(rows, valid, interpret=interpret)
+    if valid is None:
+        valid = jnp.ones(rows.shape[:2], dtype=jnp.bool_)
     return ref.xor_encode(rows, valid)
 
 
 def xor_decode(coded: jnp.ndarray, known_rows: jnp.ndarray,
                known_valid: jnp.ndarray, *, use_kernel: bool = True,
-               interpret: bool = True) -> jnp.ndarray:
+               interpret: bool | None = None) -> jnp.ndarray:
     """coded [C, W]; known_rows [r-1, C, W]; -> missing segments [C, W]."""
     strip = xor_encode(known_rows, known_valid, use_kernel=use_kernel,
                        interpret=interpret)
     return jnp.bitwise_xor(coded, strip)
 
 
+def xor_encode_rows(rows: jnp.ndarray, *, lanes: int = 128,
+                    use_kernel: bool = True,
+                    interpret: bool | None = None) -> jnp.ndarray:
+    """Slot-major XOR fold: [r, N] uint32 pre-masked slot words -> [N].
+
+    Invalid slots are zero, so no validity mask is needed and the column
+    axis can be reshaped freely: it is folded into [r, N/lanes, lanes] so
+    the Pallas kernel sees VPU-shaped uint32 tiles (lane dim 128). Every
+    route below ends here.
+    """
+    r, c = rows.shape
+    if c == 0:                     # empty schedule: nothing to multicast
+        return jnp.zeros(0, jnp.uint32)
+    pad = (-c) % lanes
+    rows = jnp.pad(rows, ((0, 0), (0, pad)))
+    rows = rows.reshape(r, (c + pad) // lanes, lanes)
+    out = xor_encode(rows, None, use_kernel=use_kernel, interpret=interpret)
+    return out.reshape(-1)[:c]
+
+
 def xor_encode_columns(slot_words, *, lanes: int = 128,
                        use_kernel: bool = True,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """Batched ShufflePlan route: [C, r] uint32 slot words -> [C] coded columns.
 
     The plan executor hands over one pre-masked segment word per (column,
-    slot); invalid slots are zero, so no validity mask is needed and the
-    column axis can be reshaped freely. We fold it into [r, C/lanes, lanes]
-    so the Pallas kernel sees VPU-shaped uint32 tiles (lane dim 128) instead
-    of W=1 slivers - this is the path that feeds the kernel realistic
-    workloads (C ~ thousands of coded columns per Shuffle).
+    slot); the slot axis is moved first and folded by `xor_encode_rows` -
+    this is the path that feeds the kernel realistic workloads (C ~
+    thousands of coded columns per Shuffle).
 
     Batched-payload route: [C, r, B] slot words (B query payloads per slot,
     the multi-query Shuffle) fold the payload axis into the column axis -
@@ -43,26 +68,16 @@ def xor_encode_columns(slot_words, *, lanes: int = 128,
     slice.
     """
     slot_words = jnp.asarray(slot_words, jnp.uint32)
-    if slot_words.ndim == 3:                            # [C, r, B] payloads
-        c, r, b = slot_words.shape
-        folded = jnp.swapaxes(slot_words, 1, 2).reshape(c * b, r)
-        out = xor_encode_columns(folded, lanes=lanes, use_kernel=use_kernel,
-                                 interpret=interpret)
-        return out.reshape(c, b)
-    c, r = slot_words.shape
-    if c == 0:                     # empty schedule: nothing to multicast
-        return jnp.zeros(0, jnp.uint32)
-    pad = (-c) % lanes
-    rows = jnp.pad(slot_words, ((0, pad), (0, 0))).T    # [r, C+pad]
-    rows = rows.reshape(r, (c + pad) // lanes, lanes)
-    valid = jnp.ones(rows.shape[:2], dtype=jnp.bool_)
-    out = xor_encode(rows, valid, use_kernel=use_kernel, interpret=interpret)
-    return out.reshape(-1)[:c]
+    r = slot_words.shape[1]
+    rows = jnp.moveaxis(slot_words, 1, 0)              # [r, C(, B)]
+    out = xor_encode_rows(rows.reshape(r, -1), lanes=lanes,
+                          use_kernel=use_kernel, interpret=interpret)
+    return out.reshape(rows.shape[1:])
 
 
 def xor_strip_columns(slot_words, *, lanes: int = 128,
                       use_kernel: bool = True,
-                      interpret: bool = True) -> jnp.ndarray:
+                      interpret: bool | None = None) -> jnp.ndarray:
     """Per-slot strip words: [C, r] with strip[:, t] = XOR of the OTHER slots.
 
     This is the receiver side of the coded Shuffle: the receiver at slot t
@@ -73,7 +88,7 @@ def xor_strip_columns(slot_words, *, lanes: int = 128,
     is axis 1 in both layouts).
     """
     slot_words = jnp.asarray(slot_words, jnp.uint32)
-    _, r = slot_words.shape
+    _, r = slot_words.shape[:2]
     cols = []
     for t in range(r):
         others = slot_words.at[:, t].set(jnp.uint32(0))
@@ -86,19 +101,20 @@ def xor_strip_columns(slot_words, *, lanes: int = 128,
 def xor_encode_slots(loc: jnp.ndarray, idx: jnp.ndarray, shift: jnp.ndarray,
                      mask: jnp.ndarray, *, lanes: int = 128,
                      use_kernel: bool = True,
-                     interpret: bool = True) -> jnp.ndarray:
-    """Per-shard fused-path encode: one server's packed coded buffer.
+                     interpret: bool | None = None) -> jnp.ndarray:
+    """Per-shard encode: one server's packed coded buffer.
 
     Gathers the server's slot words from its local value vector, aligns each
     segment (left-shift + keep-mask, zero for sentinel slots), then XOR-folds
-    the r slots through the batched column route above - so the multi-device
-    shard_map path and the single-host ShufflePlan executor share one kernel.
+    the r slots through the kernel's column route.
 
     loc [L+1] uint32 local words (last entry 0 = sentinel); idx [W, r] int
     into loc; shift/mask [W, r] uint32 -> [W] uint32 coded columns.
     Batched loc [L+1, B] (B payload words per local value) gathers to
     [W, r, B], the shift/mask tables broadcast behind the payload axis, and
-    the batched-column route returns [W, B] coded columns.
+    the batched-column route returns [W, B] coded columns. The fused
+    exchange stores these tables long axis last and folds through
+    `xor_encode_rows` itself (`core.fused_shuffle.shard_encoder`).
     """
     gathered = loc[idx]
     if gathered.ndim == 3:

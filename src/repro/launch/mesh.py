@@ -86,31 +86,12 @@ class Topology:
                 * np.int32(self.servers_per_rack))
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = True):
-    """shard_map across the jax versions we support.
-
-    jax >= 0.6 exposes jax.shard_map with `check_vma`; 0.4.x has the
-    experimental shard_map with the equivalent `check_rep`. `check=False`
-    disables the output-replication check (needed when out_specs promise
-    more replication than the checker can prove, e.g. psum-ed outputs).
-    """
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=check)
-
-
 def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
               prefix: bool = False):
     """The one mesh-construction path (every make_* helper routes here).
 
     `prefix=False` builds a mesh over *all* devices via `jax.make_mesh`
-    (with Auto axis types on jax >= 0.5; on 0.4.x the argument does not
-    exist and Auto is the only behavior, so omitting it is equivalent).
+    with Auto axis types.
 
     `prefix=True` builds the Mesh explicitly from a device *prefix* of
     ``prod(shape)`` devices - `jax.make_mesh` wants the axis sizes to
@@ -130,11 +111,8 @@ def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...], *,
                 f"{len(devs)} devices exist; force host devices with "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count={need}")
         return Mesh(np.asarray(devs[:need]).reshape(shape), axes)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_mesh_auto(shape, axes):
@@ -174,8 +152,3 @@ def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     return make_mesh((data, model), ("data", "model"))
 
-
-# TPU v5e hardware constants used by the roofline (per chip).
-PEAK_FLOPS_BF16 = 197e12       # FLOP/s
-HBM_BW = 819e9                 # bytes/s
-ICI_BW = 50e9                  # bytes/s per link

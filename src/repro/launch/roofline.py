@@ -2,18 +2,47 @@
 
   compute    = HLO_FLOPs / (chips * peak_FLOP/s)
   memory     = HLO_bytes / (chips * HBM_bw)
-  collective = collective_bytes / (chips * link_bw)
+  collective = collective_bytes / (chips * ICI_bw)
 
 cost_analysis() reports per-device numbers for SPMD modules; collective bytes
 are parsed from the optimized HLO text (all-gather / all-reduce /
 reduce-scatter / all-to-all / collective-permute operand sizes).
+
+Peaks are looked up by `jax.Device.device_kind` in `PEAKS`, never assumed:
+a device that is not in the table raises.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+
+    flops_bf16: float            # FLOP/s
+    hbm_bw: float                # bytes/s
+    ici_bw: float                # bytes/s, all chip-to-chip links together
+    source: str
+
+
+# Keyed by `jax.Device.device_kind` (a v5e chip reports "TPU v5 lite").
+PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops_bf16=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '819 GB/s HBM, 1,600 Gbit/s chip-to-chip interconnect'),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The published peaks of one chip of `device_kind`."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no published peaks for device kind {device_kind!r};"
+                       f" known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -69,21 +98,19 @@ class Roofline:
     coll_bytes_per_device: float
     coll_breakdown: dict[str, int]
     chips: int
-    peak_flops: float = PEAK_FLOPS_BF16
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
+    peak: Peaks
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / self.peak_flops
+        return self.flops_per_device / self.peak.flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / self.hbm_bw
+        return self.bytes_per_device / self.peak.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_device / self.ici_bw
+        return self.coll_bytes_per_device / self.peak.ici_bw
 
     @property
     def bottleneck(self) -> str:
@@ -101,7 +128,8 @@ class Roofline:
         """MODEL_FLOPS / (step_time * peak): the roofline fraction score."""
         if self.step_time == 0:
             return 0.0
-        return model_flops_per_device / (self.step_time * self.peak_flops)
+        return model_flops_per_device / (self.step_time
+                                         * self.peak.flops_bf16)
 
     def as_dict(self) -> dict:
         return {
@@ -131,22 +159,20 @@ class PhaseRoofline:
 
     ``fraction`` is the %-of-roofline number: achieved bandwidth over the
     roof bandwidth, i.e. how close the measured phase runs to the best the
-    bounding resource allows. Measured on CPU this is a *methodology*
-    fidelity number (the roofs are the TPU v5e constants in launch/mesh.py);
-    on real hardware the same spans produce the real figure.
+    bounding resource allows. It means something only for seconds measured
+    on the chip whose `peak` it is judged against.
     """
 
     phase: str
     seconds: float
     bytes_moved: float
     roof: str                    # "hbm" | "ici"
+    peak: Peaks
     chips: int = 1
-    hbm_bw: float = HBM_BW
-    ici_bw: float = ICI_BW
 
     @property
     def roof_bw(self) -> float:
-        bw = self.hbm_bw if self.roof == "hbm" else self.ici_bw
+        bw = self.peak.hbm_bw if self.roof == "hbm" else self.peak.ici_bw
         return bw * self.chips
 
     @property
@@ -170,33 +196,32 @@ class PhaseRoofline:
 
 
 def phase_roofline(phase: str, seconds: float, bytes_moved: float, *,
-                   chips: int = 1) -> PhaseRoofline:
-    """Judge one measured phase against its roof (see `PHASE_ROOFS`)."""
+                   device_kind: str, chips: int = 1) -> PhaseRoofline:
+    """Judge one phase measured on `device_kind` against its roof (see
+    `PHASE_ROOFS`); raises for a device with no published peaks."""
     short = phase.split(".")[-1]
     if short not in PHASE_ROOFS:
         raise ValueError(
             f"unknown phase {phase!r}; known: {sorted(PHASE_ROOFS)}")
     return PhaseRoofline(short, seconds, bytes_moved, PHASE_ROOFS[short],
-                         chips=chips)
+                         peaks(device_kind), chips=chips)
 
 
-def from_compiled(compiled, chips: int) -> Roofline:
+def from_compiled(compiled, chips: int, device_kind: str) -> Roofline:
     """Trip-aware terms from the optimized HLO (see hlo_analysis.py: XLA's
     cost_analysis counts scan bodies once, 24-62x off for deep stacks)."""
     from .hlo_analysis import analyze
     cost = analyze(compiled.as_text())
     breakdown = {k: int(v) for k, v in cost.coll_breakdown.items()}
     return Roofline(cost.flops, cost.bytes_accessed, cost.collective_bytes,
-                    breakdown, chips)
+                    breakdown, chips, peaks(device_kind))
 
 
-def from_compiled_xla(compiled, chips: int) -> Roofline:
+def from_compiled_xla(compiled, chips: int, device_kind: str) -> Roofline:
     """The raw (trip-blind) XLA numbers - kept for comparison/debugging."""
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):              # jax 0.4.x: one dict per computation
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))
     breakdown = collective_bytes(compiled.as_text())
     coll = float(sum(breakdown.values()))
-    return Roofline(flops, byts, coll, breakdown, chips)
+    return Roofline(flops, byts, coll, breakdown, chips, peaks(device_kind))

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig, MoEConfig
-from ..launch.mesh import shard_map_compat
 from ..sharding import rules
 
 
@@ -88,8 +87,8 @@ def moe_ffn_ep(p, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     in_specs = (P("data", None, None), P(), P("data", None, "model"),
                 P("data", None, "model"), P("data", "model", None))
     args = [x, p["router"], p["w_gate"], p["w_up"], p["w_down"]]
-    f = shard_map_compat(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=P("data", None, None), check=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                      out_specs=P("data", None, None), check_vma=False)
     out = f(*args)
     if e.num_shared:
         # Shared expert stays on the standard dense GeGLU path outside the

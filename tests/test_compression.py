@@ -38,10 +38,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.train.compression import compressed_psum_mean, ef_compress_tree, ef_state
 
-from repro.launch.mesh import shard_map_compat
-
 def shard_map(f, **kw):
-    return shard_map_compat(f, check=False, **kw)
+    return jax.shard_map(f, check_vma=False, **kw)
 
 mesh = jax.make_mesh((4,), ("dp",))
 

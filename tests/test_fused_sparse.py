@@ -161,6 +161,13 @@ C = int(plan.col_sender.size) + int(plan.left_k.size)
 out["per_device_loc"] = bool(s.Lmax <= 2 * r * nnz // K + 8)
 out["per_device_cols"] = bool(s.W <= 2 * C // K + 8)
 out["per_device_deliveries"] = bool(s.Dmax <= 2 * M // K + 8)
+
+# Placement: each device holds its own server's block of every table, so
+# nothing is staged whole on one device and resharded per call.
+out["tables_placed_per_device"] = bool(all(
+    len({sh.device.id for sh in a.addressable_shards}) == K
+    and all(sh.data.shape[0] == 1 for sh in a.addressable_shards)
+    for a in fx._dev_tables))
 print(json.dumps(out))
 """
 
@@ -202,3 +209,4 @@ def test_fused_engine_acceptance_and_beyond_dense_limit():
     assert res["per_device_loc"]
     assert res["per_device_cols"]
     assert res["per_device_deliveries"]
+    assert res["tables_placed_per_device"]

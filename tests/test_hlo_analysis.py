@@ -61,10 +61,7 @@ def test_xla_cost_analysis_undercounts_but_we_dont():
         return jax.lax.scan(body, x, w)[0]
 
     compiled = _compile(scanned, xs, ws)
-    ca = compiled.cost_analysis()
-    if isinstance(ca, list):              # jax 0.4.x: one dict per computation
-        ca = ca[0]
-    xla_flops = ca["flops"]
+    xla_flops = compiled.cost_analysis()["flops"]
     ours = analyze(compiled.as_text()).flops
     want = 2 * 16 * D * D * T
     assert xla_flops < want / 2          # XLA counts the body once

@@ -71,6 +71,25 @@ def test_xor_roundtrip_recovers_missing_row(r):
     np.testing.assert_array_equal(np.asarray(dec), want)
 
 
+@pytest.mark.parametrize("w,r,b", [(1000, 2, None), (300, 3, 4), (0, 2, None),
+                                   (0, 2, 3)])
+def test_xor_encode_slots_matches_numpy(w, r, b):
+    """The per-shard encode (gather, align, XOR-fold through the kernel's
+    mask-free route) against a plain NumPy fold; b is the payload width."""
+    L = 500
+    loc = RNG.integers(0, 2**32, size=(L + 1,) if b is None else (L + 1, b),
+                       dtype=np.uint32)
+    loc[L] = 0                                       # sentinel
+    idx = RNG.integers(0, L + 1, size=(w, r)).astype(np.int32)
+    shift = RNG.integers(0, 32, size=(w, r)).astype(np.uint32)
+    mask = RNG.integers(0, 2**32, size=(w, r), dtype=np.uint32)
+    bx = (lambda a: a) if b is None else (lambda a: a[..., None])
+    slotw = (loc[idx] << bx(shift)) & bx(mask)       # [W, r(, B)]
+    want = np.bitwise_xor.reduce(slotw, axis=1)
+    got = xor_ops.xor_encode_slots(*map(jnp.asarray, (loc, idx, shift, mask)))
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 def test_xor_float_bitcast_roundtrip():
     x = RNG.standard_normal(64).astype(np.float32)
     w = xor_ops.floats_as_words(jnp.array(x))
