@@ -23,22 +23,22 @@ def _modules():
     try:
         from . import (batched_sweep, coded_moe_dispatch, delta_sweep,
                        fig5_load_curve, fused_sweep, hierarchy_sweep,
-                       kernel_bench, pagerank_phases, phase_profile,
-                       recovery_bench, scale_sweep, straggler_bench,
-                       table2_snap, theorem_tradeoffs)
+                       kernel_bench, pagerank_phases, recovery_bench,
+                       scale_sweep, straggler_bench, table2_snap,
+                       theorem_tradeoffs)
     except ImportError:
         root = pathlib.Path(__file__).resolve().parents[1]
         sys.path[:0] = [str(root), str(root / "src")]
         from benchmarks import (batched_sweep, coded_moe_dispatch,
                                 delta_sweep, fig5_load_curve, fused_sweep,
                                 hierarchy_sweep, kernel_bench,
-                                pagerank_phases, phase_profile,
-                                recovery_bench, scale_sweep, straggler_bench,
-                                table2_snap, theorem_tradeoffs)
+                                pagerank_phases, recovery_bench,
+                                scale_sweep, straggler_bench, table2_snap,
+                                theorem_tradeoffs)
     return (fig5_load_curve, theorem_tradeoffs, pagerank_phases, scale_sweep,
             batched_sweep, fused_sweep, kernel_bench, coded_moe_dispatch,
-            straggler_bench, table2_snap, recovery_bench, phase_profile,
-            delta_sweep, hierarchy_sweep)
+            straggler_bench, table2_snap, recovery_bench, delta_sweep,
+            hierarchy_sweep)
 
 
 def main(argv: list[str] | None = None) -> None:

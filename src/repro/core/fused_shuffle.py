@@ -573,8 +573,17 @@ class FusedSparseShuffle:
     Given a `HierarchicalPlan` (or a non-flat `topology=` plus one), the
     exchange runs the two-level ('racks' x 'servers') pipeline instead -
     see the module docstring - with `bits_sent` split into
-    inter-rack/intra-rack on the exchange span and the metrics registry.
+    inter-rack/intra-rack on the exchange span.
     `Topology.flat(K)` degenerates to the single-level exchange.
+
+    Instrumentation (all of it off the device program's data path): host
+    spans `exchange.partition`, `exchange.place_tables`, `exchange.place`,
+    `exchange.first_call` / `exchange.run`, `decode.pull`, `decode.reindex`
+    and `phase.codec`; device scopes `exchange.encode`, `exchange.gather`
+    and `exchange.decode` (`jax.named_scope`, HLO metadata only); and the
+    registry counters `exchange_calls_total`,
+    `exchange_host_copy_bytes_total`, `exchange_bits_useful_total` and
+    `exchange_bits_sent_total`.
 
     encode:
       "xor-ref"    - batched kernels/xor_code route, jnp oracle (default).
@@ -607,6 +616,7 @@ class FusedSparseShuffle:
         # One jitted exchange serves [nnz] and [nnz, B] payloads alike: jit
         # traces each payload shape once and replays it.
         self._fn = build(encode, self._interpret)
+        self._seen = set()                    # call shapes `_fn` has compiled
         self._dev_tables = None               # placed on the first exchange
 
     def _bind(self, plan, csr, alloc, topology) -> None:
@@ -631,16 +641,24 @@ class FusedSparseShuffle:
                 "ShufflePlan")
         self.topology = topology
         self._hier = isinstance(plan, HierarchicalPlan)
-        if self._hier:
-            self.hplan = plan
-            self.plan = plan.flat
-            self.sched = partition_hierarchical(plan, csr, alloc)
-            self._schedule_bits = plan.inter_rack_bits + plan.intra_rack_bits
-        else:
-            self.hplan = None
-            self.plan = plan
-            self.sched = partition_plan(plan, csr, alloc)
-            self._schedule_bits = plan.coded_bits + plan.leftover_bits
+        with get_tracer().span("exchange.partition", hier=self._hier):
+            if self._hier:
+                self.hplan = plan
+                self.plan = plan.flat
+                self.sched = partition_hierarchical(plan, csr, alloc)
+                self._schedule_bits = (plan.inter_rack_bits
+                                       + plan.intra_rack_bits)
+            else:
+                self.hplan = None
+                self.plan = plan
+                self.sched = partition_plan(plan, csr, alloc)
+                self._schedule_bits = plan.coded_bits + plan.leftover_bits
+        s = self.sched
+        # Words the chips put into the collectives per payload column, each
+        # buffer counted once (a multicast): the K coded [W] buffers, or on
+        # two levels the R rack buffers plus every server's phase-A words.
+        self._sent_words = (s.R * s.Wx + s.K * (s.Lmax + 1) if self._hier
+                            else s.K * s.W)
 
     def _in_specs(self) -> tuple:
         """The exchange's shard_map in_specs: loc, then the device tables.
@@ -706,6 +724,7 @@ class FusedSparseShuffle:
         ex._encode = self._encode
         ex._interpret = self._interpret
         ex._fn = self._fn
+        ex._seen = self._seen
         ex._dev_tables = None
         return ex
 
@@ -720,14 +739,17 @@ class FusedSparseShuffle:
         def per_server(loc, enc_l, enc_shift, enc_mask, dec_s, dec_w,
                        dec_mask, dec_shift, strip_l, strip_shift, strip_mask):
             loc = loc[0]                          # [(B,) Lmax+1]
-            coded = encode_shard(loc, enc_l[0], enc_shift[0], enc_mask[0])
-            allbufs = _gather_servers(coded, "servers")     # [(B,) K, W]
-            allbufs = _pad_last(allbufs)                    # zero col W
-            got = take(allbufs, dec_s[0], dec_w[0])         # [(B,) r, Dmax]
-            sw = (take(loc, strip_l[0]) << strip_shift[0]) & strip_mask[0]
-            strip = _fold(sw, -2, jax.lax.bitwise_xor)      # [(B,) r, Dmax]
-            rec = ((got ^ strip) & dec_mask[0]) >> dec_shift[0]
-            return _fold(rec, -2, jax.lax.bitwise_or)[None]  # [1, (B,) Dmax]
+            with jax.named_scope("exchange.encode"):
+                coded = encode_shard(loc, enc_l[0], enc_shift[0], enc_mask[0])
+            with jax.named_scope("exchange.gather"):
+                allbufs = _gather_servers(coded, "servers")  # [(B,) K, W]
+                allbufs = _pad_last(allbufs)                 # zero col W
+            with jax.named_scope("exchange.decode"):
+                got = take(allbufs, dec_s[0], dec_w[0])      # [(B,) r, Dmax]
+                sw = (take(loc, strip_l[0]) << strip_shift[0]) & strip_mask[0]
+                strip = _fold(sw, -2, jax.lax.bitwise_xor)   # [(B,) r, Dmax]
+                rec = ((got ^ strip) & dec_mask[0]) >> dec_shift[0]
+                return _fold(rec, -2, jax.lax.bitwise_or)[None]  # [1, (B,) Dmax]
 
         # pallas_call has no replication rule, so the kernel route must
         # disable the output-replication checker (outputs are per-shard
@@ -748,25 +770,31 @@ class FusedSparseShuffle:
             # Phase A: plain all_gather of local Map words on the cheap
             # intra-rack axis -> the rack's union buffer, on every member
             # (block s of rflat = server s of the rack).
-            rloc = _gather_servers(loc, "servers")  # [(B,) S, Lmax+1]
-            rflat = rloc.reshape(loc.shape[:-1] + (-1,))  # [(B,) S(Lmax+1)]
+            with jax.named_scope("exchange.gather"):
+                rloc = _gather_servers(loc, "servers")  # [(B,) S, Lmax+1]
+                rflat = rloc.reshape(loc.shape[:-1] + (-1,))  # [(B,) S(Lmax+1)]
             # Phase B: rack-level coded encode (replicated within the rack:
             # every member computes the same buffer from rflat - recompute
             # beats a leader branch) + one coded XOR all_gather on the
             # expensive inter-rack axis.
-            coded = encode_shard(rflat, enc_l[0], enc_shift[0], enc_mask[0])
-            allbufs = _gather_servers(coded, "racks")       # [(B,) R, Wx]
-            allbufs = _pad_last(allbufs)                    # zero col Wx
-            got = take(allbufs, dec_rk[0, 0], dec_w[0, 0])  # [(B,) rr, Dmax]
-            sw = ((take(rflat, strip_f[0, 0]) << strip_shift[0, 0])
-                  & strip_mask[0, 0])
-            strip = _fold(sw, -2, jax.lax.bitwise_xor)
-            rec = ((got ^ strip) & dec_mask[0, 0]) >> dec_shift[0, 0]
-            words = _fold(rec, -2, jax.lax.bitwise_or)      # [(B,) Dmax]
-            # Intra-only deliveries never crossed a rack: direct gather
-            # from the phase-A buffer (mask 0 on inter deliveries).
-            words = words | (take(rflat, direct_l[0, 0]) & direct_mask[0, 0])
-            return words[None, None]              # [1, 1, (B,) Dmax]
+            with jax.named_scope("exchange.encode"):
+                coded = encode_shard(rflat, enc_l[0], enc_shift[0],
+                                     enc_mask[0])
+            with jax.named_scope("exchange.gather"):
+                allbufs = _gather_servers(coded, "racks")    # [(B,) R, Wx]
+                allbufs = _pad_last(allbufs)                 # zero col Wx
+            with jax.named_scope("exchange.decode"):
+                got = take(allbufs, dec_rk[0, 0], dec_w[0, 0])  # [(B,) rr, Dmax]
+                sw = ((take(rflat, strip_f[0, 0]) << strip_shift[0, 0])
+                      & strip_mask[0, 0])
+                strip = _fold(sw, -2, jax.lax.bitwise_xor)
+                rec = ((got ^ strip) & dec_mask[0, 0]) >> dec_shift[0, 0]
+                words = _fold(rec, -2, jax.lax.bitwise_or)   # [(B,) Dmax]
+                # Intra-only deliveries never crossed a rack: direct gather
+                # from the phase-A buffer (mask 0 on inter deliveries).
+                words = words | (take(rflat, direct_l[0, 0])
+                                 & direct_mask[0, 0])
+                return words[None, None]          # [1, 1, (B,) Dmax]
 
         f = jax.shard_map(
             per_server, mesh=self.mesh,
@@ -791,16 +819,21 @@ class FusedSparseShuffle:
         dev = self.exchange_device(edge_words)
         plan = self.plan
         B = int(edge_words.shape[1]) if np.ndim(edge_words) == 2 else 1
-        with get_tracer().span("phase.decode", backend="fused", B=B,
-                               deliveries=int(plan.all_k.size)):
-            out = np.asarray(dev)                 # [K, (B,) Dmax]
-            if self._hier:
-                out = out.reshape((plan.K,) + out.shape[2:])
-            M = plan.all_k.size
-            # [M] or, batched, [M, B] (the sliced B axis sits between the
-            # two index arrays, so numpy puts it last)
-            return out[plan.all_k, ..., np.arange(M, dtype=np.int64)
-                       - plan.ptr[plan.all_k]]
+        tr = get_tracer()
+        with tr.span("phase.decode", backend="fused", B=B,
+                     deliveries=int(plan.all_k.size)):
+            with tr.span("decode.pull"):
+                out = np.asarray(dev)             # [K, (B,) Dmax]
+            get_registry().counter("exchange_host_copy_bytes_total") \
+                .inc(out.nbytes)
+            with tr.span("decode.reindex"):
+                if self._hier:
+                    out = out.reshape((plan.K,) + out.shape[2:])
+                M = plan.all_k.size
+                # [M] or, batched, [M, B] (the sliced B axis sits between
+                # the two index arrays, so numpy puts it last)
+                return out[plan.all_k, ..., np.arange(M, dtype=np.int64)
+                           - plan.ptr[plan.all_k]]
 
     def exchange_device(self, edge_words: np.ndarray) -> jax.Array:
         """Encode + exchange of `exchange_words`, left on the mesh.
@@ -838,32 +871,47 @@ class FusedSparseShuffle:
         # collective's execution, not just its dispatch.
         with tr.span("phase.exchange", **attrs):
             if self._dev_tables is None:
-                self._dev_tables = self._place(self._host_tables(),
-                                               self._in_specs()[1:])
-            (loc,) = self._place((loc,), self._in_specs()[:1])
-            dev = self._fn(loc, *self._dev_tables)
-            jax.block_until_ready(dev)
-        if self._hier:
-            reg = get_registry()
-            reg.counter("shuffle_inter_rack_bits_total",
-                        "coded-Shuffle bits crossing rack boundaries") \
-                .inc(self.hplan.inter_rack_bits * B)
-            reg.counter("shuffle_intra_rack_bits_total",
-                        "coded-Shuffle bits moving inside racks") \
-                .inc(self.hplan.intra_rack_bits * B)
+                with tr.span("exchange.place_tables"):
+                    self._dev_tables = self._place(self._host_tables(),
+                                                   self._in_specs()[1:])
+            with tr.span("exchange.place"):
+                (dloc,) = self._place((loc,), self._in_specs()[:1])
+            # A call shape new to the jitted exchange traces and compiles
+            # (or loads from the compile cache) inside the call.
+            key = (loc.shape,) + tuple(t.shape for t in self._dev_tables)
+            first = key not in self._seen
+            with tr.span("exchange.first_call" if first else "exchange.run"):
+                dev = self._fn(dloc, *self._dev_tables)
+                jax.block_until_ready(dev)
+            self._seen.add(key)
+        reg = get_registry()
+        reg.counter("exchange_calls_total",
+                    "fused coded-Shuffle exchanges run").inc()
+        reg.counter("exchange_host_copy_bytes_total",
+                    "bytes copied between host and chips per exchange: local "
+                    "words in, padded deliveries out").inc(loc.nbytes)
+        reg.counter("exchange_bits_useful_total",
+                    "Definition 2 load bits the schedule needs").inc(bits)
+        reg.counter("exchange_bits_sent_total",
+                    "bits the chips put into the exchange's collectives, "
+                    "each buffer once").inc(32 * self._sent_words * B)
         return dev
 
     def execute(self, edge_vals: np.ndarray) -> PlanShuffleResult:
         """Drop-in peer of `ShufflePlan.execute_coded_sparse` (batched
         [nnz, B] edge values supported the same way)."""
         plan = self.plan
-        edge_vals = np.asarray(edge_vals, np.float32)
-        words = self.exchange_words(floats_to_words(edge_vals))
+        tr = get_tracer()
+        with tr.span("phase.codec"):
+            edge_vals = np.asarray(edge_vals, np.float32)
+            edge_words = floats_to_words(edge_vals)
+        words = self.exchange_words(edge_words)
+        with tr.span("phase.codec"):
+            values = words_to_floats(words)
         bits = (self._schedule_bits
                 * (edge_vals.shape[1] if edge_vals.ndim == 2 else 1))
         return PlanShuffleResult(plan.all_k, plan.all_i, plan.all_j,
-                                 words_to_floats(words), plan.ptr, bits,
-                                 plan.n)
+                                 values, plan.ptr, bits, plan.n)
 
 
 def run_fused_sparse(g: Graph, edge_vals: np.ndarray, alloc: Allocation,

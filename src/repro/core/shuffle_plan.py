@@ -353,30 +353,31 @@ class ShufflePlan:
                 return tables
             # Re-bound to a different (csr, alloc): rebuild rather than
             # silently serving stale gather tables.
-        pair_e = _locate_edges(csr, self.pair_i, self.pair_j)
-        left_e = _locate_edges(csr, self.left_i, self.left_j)
-        all_e = _locate_edges(csr, self.all_i, self.all_j)
-        # Reduce gather: local Map output where the owner Mapped the source,
-        # the (k, i, j)-sorted delivery slot otherwise.
-        n = np.int64(self.n)
-        owners = alloc.reduce_owner[csr.rows]
-        local = alloc.map_sets[owners, csr.indices]
-        gather = np.arange(csr.nnz, dtype=np.int64)
-        missing = ~local
-        all_key = ((self.all_k.astype(np.int64) * n + self.all_i) * n
-                   + self.all_j)
-        need_key = ((owners[missing].astype(np.int64) * n
-                     + csr.rows[missing]) * n + csr.indices[missing])
-        pos = np.searchsorted(all_key, need_key)
-        ok = (pos < all_key.size) & (all_key[np.minimum(pos, all_key.size - 1)]
-                                     == need_key)
-        if not ok.all():
-            miss = np.flatnonzero(missing)[~ok][:5]
-            raise RuntimeError(
-                f"schedule incomplete: no delivery for CSR entries "
-                f"{list(zip(csr.rows[miss].tolist(), csr.indices[miss].tolist()))}")
-        gather[missing] = csr.nnz + pos
-        tables = PlanEdgeTables(pair_e, left_e, all_e, gather)
+        with get_tracer().span("plan.edge_tables", nnz=csr.nnz):
+            pair_e = _locate_edges(csr, self.pair_i, self.pair_j)
+            left_e = _locate_edges(csr, self.left_i, self.left_j)
+            all_e = _locate_edges(csr, self.all_i, self.all_j)
+            # Reduce gather: local Map output where the owner Mapped the source,
+            # the (k, i, j)-sorted delivery slot otherwise.
+            n = np.int64(self.n)
+            owners = alloc.reduce_owner[csr.rows]
+            local = alloc.map_sets[owners, csr.indices]
+            gather = np.arange(csr.nnz, dtype=np.int64)
+            missing = ~local
+            all_key = ((self.all_k.astype(np.int64) * n + self.all_i) * n
+                       + self.all_j)
+            need_key = ((owners[missing].astype(np.int64) * n
+                         + csr.rows[missing]) * n + csr.indices[missing])
+            pos = np.searchsorted(all_key, need_key)
+            ok = (pos < all_key.size) & (all_key[np.minimum(pos, all_key.size - 1)]
+                                         == need_key)
+            if not ok.all():
+                miss = np.flatnonzero(missing)[~ok][:5]
+                raise RuntimeError(
+                    f"schedule incomplete: no delivery for CSR entries "
+                    f"{list(zip(csr.rows[miss].tolist(), csr.indices[miss].tolist()))}")
+            gather[missing] = csr.nnz + pos
+            tables = PlanEdgeTables(pair_e, left_e, all_e, gather)
         self.__dict__["_edge_tables"] = (csr, alloc, tables)
         return tables
 
@@ -1469,7 +1470,8 @@ class HierarchicalPlan:
         self.flat.check_alloc(alloc)
 
     def edge_tables(self, csr: CSR, alloc: Allocation) -> HierarchicalEdgeTables:
-        """Bind both levels to a CSR view (cached, like the flat form)."""
+        """Bind both levels to a CSR view (cached, like the flat form; each
+        level's real work is its own `plan.edge_tables` span)."""
         cached = self.__dict__.get("_h_edge_tables")
         if cached is not None:
             c_csr, c_alloc, tables = cached
@@ -1491,11 +1493,9 @@ class HierarchicalPlan:
         `execute_coded_sparse` (same stream, exact XOR recovery at the rack
         level, direct words at the intra level); `bits_sent` is the
         two-level total `inter_rack_bits + intra_rack_bits` (x B for
-        batched [nnz, B] payloads). The exchange span and the metrics
-        registry carry both per-level numbers.
+        batched [nnz, B] payloads). The exchange span carries both
+        per-level numbers.
         """
-        from ..obs.metrics import get_registry
-
         res_x = self.inter.execute_coded_sparse(
             edge_vals, tables.inter, backend=backend, interpret=interpret)
         B = res_x.batch
@@ -1511,13 +1511,6 @@ class HierarchicalPlan:
                                inter_rack_bits=inter_bits,
                                intra_rack_bits=intra_bits):
             pass
-        reg = get_registry()
-        reg.counter("shuffle_inter_rack_bits_total",
-                    "coded-Shuffle bits crossing rack boundaries") \
-            .inc(inter_bits)
-        reg.counter("shuffle_intra_rack_bits_total",
-                    "coded-Shuffle bits moving inside racks") \
-            .inc(intra_bits)
         return PlanShuffleResult(self.flat.all_k, self.flat.all_i,
                                  self.flat.all_j, out, self.flat.ptr,
                                  inter_bits + intra_bits, self.flat.n)

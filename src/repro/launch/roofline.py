@@ -144,69 +144,6 @@ class Roofline:
         }
 
 
-# Which roof each measured engine phase is judged against: the exchange is
-# wire traffic (ICI links); every other phase is host/device memory
-# streaming (HBM). See benchmarks/phase_profile.py for the producer.
-PHASE_ROOFS = {
-    "map": "hbm", "encode": "hbm", "exchange": "ici",
-    "decode": "hbm", "reduce": "hbm",
-}
-
-
-@dataclasses.dataclass(frozen=True)
-class PhaseRoofline:
-    """A measured phase (seconds + bytes moved) against its bandwidth roof.
-
-    ``fraction`` is the %-of-roofline number: achieved bandwidth over the
-    roof bandwidth, i.e. how close the measured phase runs to the best the
-    bounding resource allows. It means something only for seconds measured
-    on the chip whose `peak` it is judged against.
-    """
-
-    phase: str
-    seconds: float
-    bytes_moved: float
-    roof: str                    # "hbm" | "ici"
-    peak: Peaks
-    chips: int = 1
-
-    @property
-    def roof_bw(self) -> float:
-        bw = self.peak.hbm_bw if self.roof == "hbm" else self.peak.ici_bw
-        return bw * self.chips
-
-    @property
-    def achieved_bw(self) -> float:
-        return self.bytes_moved / self.seconds if self.seconds > 0 else 0.0
-
-    @property
-    def roof_seconds(self) -> float:
-        return self.bytes_moved / self.roof_bw
-
-    @property
-    def fraction(self) -> float:
-        """Achieved / roof bandwidth (the %-of-roofline figure)."""
-        return self.achieved_bw / self.roof_bw
-
-    def as_dict(self) -> dict:
-        return {"phase": self.phase, "seconds": self.seconds,
-                "bytes_moved": self.bytes_moved, "roof": self.roof,
-                "achieved_bw": self.achieved_bw,
-                "roofline_fraction": self.fraction}
-
-
-def phase_roofline(phase: str, seconds: float, bytes_moved: float, *,
-                   device_kind: str, chips: int = 1) -> PhaseRoofline:
-    """Judge one phase measured on `device_kind` against its roof (see
-    `PHASE_ROOFS`); raises for a device with no published peaks."""
-    short = phase.split(".")[-1]
-    if short not in PHASE_ROOFS:
-        raise ValueError(
-            f"unknown phase {phase!r}; known: {sorted(PHASE_ROOFS)}")
-    return PhaseRoofline(short, seconds, bytes_moved, PHASE_ROOFS[short],
-                         peaks(device_kind), chips=chips)
-
-
 def from_compiled(compiled, chips: int, device_kind: str) -> Roofline:
     """Trip-aware terms from the optimized HLO (see hlo_analysis.py: XLA's
     cost_analysis counts scan bodies once, 24-62x off for deep stacks)."""

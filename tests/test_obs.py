@@ -92,6 +92,7 @@ def test_pinned_span_tree_coded_run(tracer):
     iteration = ("engine.iteration", tuple((p, ()) for p in PHASES))
     assert tracer.tree() == (
         ("engine.compile", (("plan.compile", ()),)),
+        ("plan.edge_tables", ()),
         ("engine.run", (iteration,) * 3),
     )
 
@@ -122,9 +123,11 @@ def test_span_tree_with_faults_and_checkpoints(tracer, tmp_path):
     run_tree = next(r.tree() for r in tracer.roots if r.name == "engine.run")
     assert run_tree == ("engine.run", (
         ("engine.iteration", phases + (save,)),
-        # crash boundary: the fault event then the in-place plan surgery
+        # crash boundary: the fault event, the in-place plan surgery, then
+        # the repaired plan's edge tables
         ("engine.iteration",
-         (("fault.crash", ()), ("plan.repair", ())) + phases + (save,)),
+         (("fault.crash", ()), ("plan.repair", ()), ("plan.edge_tables", ()))
+         + phases + (save,)),
         # recovery boundary: back on the original compiled session
         ("engine.iteration", (("fault.recover", ()),) + phases + (save,)),
     ))

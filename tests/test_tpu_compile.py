@@ -113,3 +113,7 @@ def test_fused_exchange_compiles_on_four_chips(topo, topology):
     # zero-padded buffers; either way the exchange crosses the chips.
     text = compiled.as_text()
     assert "all-gather" in text or "all-reduce" in text
+    # the exchange's named scopes survive into the chip's HLO metadata,
+    # where the profiler reads them
+    for scope in ("exchange.encode", "exchange.gather", "exchange.decode"):
+        assert f"/{scope}/" in text
