@@ -131,11 +131,12 @@ def one_chip(s: dict) -> None:
 
     dev = jax.devices()[0]
     put = functools.partial(jax.device_put, device=dev)
-    # [K, r, W]: the encode tables as the fused exchange stores them
-    enc = [lanes_last(a) for a in (sched.enc_l, sched.enc_shift,
-                                   sched.enc_mask)]
-    shapes = [jax.ShapeDtypeStruct((sched.Lmax + 1,), np.uint32)] + [
-        jax.ShapeDtypeStruct(a.shape[1:], a.dtype) for a in enc]
+    # [K, r, W]: the encode tables as the fused exchange stores them, and
+    # the CSR entry of every slot, which its host side gathers through
+    enc_e, *enc = [lanes_last(a) for a in (sched.enc_e, sched.enc_shift,
+                                           sched.enc_mask)]
+    shapes = [jax.ShapeDtypeStruct(a.shape[1:], np.uint32)
+              for a in (enc_e, *enc)]
     interpret = interpret_mode(platform=dev.platform)
     with Clock("compile the per-shard encode, kernel and xor-ref routes"):
         kernel = jax.jit(shard_encoder("xor-kernel", interpret)) \
@@ -163,11 +164,9 @@ def one_chip(s: dict) -> None:
               and got.bits_sent == want.bits_sent,
               f"(a) {want.values.size} delivered words bitwise equal")
 
-        words = np.append(floats_to_words(ev), np.uint32(0))
+        words = np.append(floats_to_words(ev), np.uint32(0))  # nnz -> 0
         for k in range(K):
-            loc = np.zeros(sched.Lmax + 1, np.uint32)
-            loc[:sched.Lmax] = words[sched.loc_e[k]]
-            args = [put(a) for a in (loc, *(t[k] for t in enc))]
+            args = [put(a) for a in (words[enc_e[k]], *(t[k] for t in enc))]
             with Clock(f"(b) server {k} kernel encode, device"):
                 coded = kernel(*args).block_until_ready()
             ref = ref_route(*args).block_until_ready()

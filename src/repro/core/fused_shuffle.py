@@ -15,11 +15,13 @@ Two executors share that design:
   * **Sparse (production path)** - `partition_plan` splits a compiled CSR
     `ShufflePlan` per server: each device holds only its own slice of the
     Map output (`loc_e`, the [nnz]-indexed values it Mapped, O(r nnz / K))
-    plus its encode/decode/strip tables (O(plan / K)). One iteration under
-    `shard_map` on a ('servers',) mesh is (a) per-shard gather-shift-mask +
-    XOR encode through the batched `kernels/xor_code` route, (b) one packed
-    dense all_gather of uint32 coded words, (c) per-shard strip + shift-back
-    into each receiver's delivery slice. No [n, n] or O(n^2)-shaped array
+    plus its encode/decode/strip tables (O(plan / K)). The host gathers
+    each server's words straight into the slot order of its encode and
+    strip tables. One iteration under `shard_map` on a ('servers',) mesh is
+    then (a) per-shard shift-mask + XOR encode through the batched
+    `kernels/xor_code` route, (b) one packed dense all_gather of uint32
+    coded words, (c) per-shard delivery gather, strip + shift-back into
+    each receiver's delivery slice. No [n, n] or O(n^2)-shaped array
     exists anywhere on this path; `FusedSparseShuffle` jits the exchange
     once and replays it every iteration, bit-exact against
     `ShufflePlan.execute_coded_sparse` (unicast leftovers ride the same
@@ -105,10 +107,17 @@ class FusedSparseSchedule:
     into a [W]-word buffer, and the `dec_*`/`strip_*` tables recover its
     delivery slice from the all_gathered [K, W] buffer matrix.
 
-    Sentinels: local index `Lmax` is a guaranteed-zero word; buffer column
-    `W` is a guaranteed-zero column (padded after the all_gather); masks of
-    sentinel slots are 0, so they OR/XOR away - encode and decode are plain
-    gather-shift-mask pipelines with no control flow.
+    `enc_e` and `strip_e` are `enc_l` and `strip_l` composed with `loc_e`:
+    the CSR entry of every encode and strip slot. The flat exchange's host
+    side gathers the edge words through them straight into slot order, so
+    its device program reads every encode and strip word in place; the
+    local-index tables stay for the two-level partition and the locality
+    checks.
+
+    Sentinels: local index `Lmax` and CSR entry `nnz` are guaranteed-zero
+    words; buffer column `W` is a guaranteed-zero column (padded after the
+    all_gather); masks of sentinel slots are 0, so they OR/XOR away -
+    encode and decode are plain shift-mask pipelines with no control flow.
     """
 
     K: int
@@ -127,6 +136,8 @@ class FusedSparseSchedule:
     strip_l: np.ndarray           # [K, Dmax, r, r-1] int32 local index
     strip_shift: np.ndarray       # [K, Dmax, r, r-1] uint32
     strip_mask: np.ndarray        # [K, Dmax, r, r-1] uint32
+    enc_e: np.ndarray             # [K, W, r] int32 CSR entry (nnz = zero)
+    strip_e: np.ndarray           # [K, Dmax, r, r-1] int32 CSR entry (nnz = zero)
 
 
 def partition_plan(plan: ShufflePlan | HierarchicalPlan, csr: CSR,
@@ -276,11 +287,22 @@ def partition_plan(plan: ShufflePlan | HierarchicalPlan, csr: CSR,
     strip_shift[kk, dd] = f_ssh
     strip_mask[kk, dd] = f_smk
 
+    # --- slot-order CSR entries: local indices composed with loc_e ---
+    if csr.nnz >= np.iinfo(np.int32).max:
+        raise ValueError(f"nnz={csr.nnz} does not fit int32 CSR entries")
+    loc_z = np.concatenate([loc_e, np.full((K, 1), csr.nnz, np.int64)],
+                           axis=1).astype(np.int32)      # [Lmax] -> nnz
+
+    def entries(local):
+        flat = np.take_along_axis(loc_z, local.reshape(K, -1), axis=1)
+        return flat.reshape(local.shape)
+
     return FusedSparseSchedule(
         K=K, r=r, W=W, Lmax=Lmax, Dmax=Dmax, loc_e=loc_e,
         enc_l=enc_l, enc_shift=enc_shift, enc_mask=enc_mask,
         dec_s=dec_s, dec_w=dec_w, dec_mask=dec_mask, dec_shift=dec_shift,
-        strip_l=strip_l, strip_shift=strip_shift, strip_mask=strip_mask)
+        strip_l=strip_l, strip_shift=strip_shift, strip_mask=strip_mask,
+        enc_e=entries(enc_l), strip_e=entries(strip_l))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -541,17 +563,20 @@ def lanes_last(a: np.ndarray) -> np.ndarray:
 def shard_encoder(encode: str, interpret: bool):
     """The exchange's per-shard encode for one `encode` backend.
 
-    loc [(B,) L+1] uint32 local words (last entry 0 = sentinel); idx, shift,
-    mask [r, W], one server's `lanes_last` encode tables -> [(B,) W] coded
-    columns. "xor-ref" and "xor-kernel" fold the slots through
-    `xor_ops.xor_encode_rows` (jnp oracle / Pallas kernel), "jnp" through
-    a plain XOR reduce; all three give the same words.
+    words [(B,) r, W] uint32, the server's value word of every encode slot
+    in slot order (0 in sentinel slots); shift, mask [r, W], one server's
+    `lanes_last` encode tables -> [(B,) W] coded columns. The flat exchange
+    passes its operand's slot words as they arrive; the two-level exchange
+    gathers them from its phase-A buffer first. "xor-ref" and "xor-kernel"
+    fold the slots through `xor_ops.xor_encode_rows` (jnp oracle / Pallas
+    kernel), "jnp" through a plain XOR reduce; all three give the same
+    words.
     """
     if encode not in ENCODE_BACKENDS:
         raise ValueError(f"unknown encode backend {encode!r}")
 
-    def enc(loc, idx, shift, mask):
-        slotw = (take(loc, idx) << shift) & mask            # [(B,) r, W]
+    def enc(words, shift, mask):
+        slotw = (words << shift) & mask                     # [(B,) r, W]
         if encode == "jnp":
             return _fold(slotw, -2, jax.lax.bitwise_xor)
         rows = jnp.moveaxis(slotw, -2, 0)                   # [r, (B,) W]
@@ -659,9 +684,20 @@ class FusedSparseShuffle:
         # two levels the R rack buffers plus every server's phase-A words.
         self._sent_words = (s.R * s.Wx + s.K * (s.Lmax + 1) if self._hier
                             else s.K * s.W)
+        self._nnz = csr.nnz
+        if not self._hier:
+            # The flat operand's gather index, [K, r W + r (r-1) Dmax] CSR
+            # entries laid as the device tables are (`lanes_last`); intp,
+            # so `np.take` converts nothing per call.
+            self._slot_e = np.concatenate(
+                [lanes_last(s.enc_e).reshape(s.K, -1),
+                 lanes_last(s.strip_e).reshape(s.K, -1)],
+                axis=1).astype(np.intp)
+        self._bufs = {}                       # host buffers per payload shape
 
     def _in_specs(self) -> tuple:
-        """The exchange's shard_map in_specs: loc, then the device tables.
+        """The exchange's shard_map in_specs: the word operand, then the
+        device tables.
 
         Flat: every array is split one server per device. Two levels: loc
         and the per-server tables split over (racks, servers); the rack
@@ -670,7 +706,22 @@ class FusedSparseShuffle:
         if self._hier:
             return ((P("racks", "servers"),) + (P("racks"),) * 3
                     + (P("racks", "servers"),) * 9)
-        return (P("servers"),) * 11
+        return (P("servers"),) * 9
+
+    def operand_shape(self, B: int | None = None) -> tuple:
+        """Shape of the word operand `exchange_device` places per call, for
+        an [nnz] payload (B None) or an [nnz, B] one.
+
+        Flat: [K, (B,) r W + r (r-1) Dmax], every server's encode words
+        then its strip words, in the device tables' slot order. Two levels:
+        [R, S, (B,) Lmax+1], every server's local Map words and the zero
+        sentinel, which phase A all_gathers within the rack.
+        """
+        s, lead = self.sched, (() if B is None else (B,))
+        if self._hier:
+            return ((self.topology.racks, self.topology.servers_per_rack)
+                    + lead + (s.Lmax + 1,))
+        return (s.K,) + lead + (self._slot_e.shape[1],)
 
     def _place(self, arrays, specs) -> tuple:
         """Host arrays -> device arrays laid out as the exchange reads them:
@@ -698,8 +749,8 @@ class FusedSparseShuffle:
                     s.strip_shift, s.strip_mask)),
                 rs(s.direct_l), rs(s.direct_mask))
         return tuple(lanes_last(a) for a in (
-            s.enc_l, s.enc_shift, s.enc_mask, s.dec_s, s.dec_w, s.dec_mask,
-            s.dec_shift, s.strip_l, s.strip_shift, s.strip_mask))
+            s.enc_shift, s.enc_mask, s.dec_s, s.dec_w, s.dec_mask,
+            s.dec_shift, s.strip_shift, s.strip_mask))
 
     def rebind(self, plan: ShufflePlan | HierarchicalPlan, csr: CSR,
                alloc: Allocation) -> "FusedSparseShuffle":
@@ -730,23 +781,30 @@ class FusedSparseShuffle:
 
     def _build(self, encode: str, interpret: bool):
         # Batched payloads put one B axis *before* every word array's long
-        # axis (loc [B, Lmax+1], buffers [B, K, W], deliveries [B, Dmax]);
+        # axis (words [B, N], buffers [B, K, W], deliveries [B, Dmax]);
         # the schedule tables are value-agnostic and broadcast against it.
         # All device ops stay uint32 shift/mask/XOR, so payload column b is
         # bitwise the unbatched exchange of column b.
         encode_shard = shard_encoder(encode, interpret)
 
-        def per_server(loc, enc_l, enc_shift, enc_mask, dec_s, dec_w,
-                       dec_mask, dec_shift, strip_l, strip_shift, strip_mask):
-            loc = loc[0]                          # [(B,) Lmax+1]
+        def per_server(words, enc_shift, enc_mask, dec_s, dec_w, dec_mask,
+                       dec_shift, strip_shift, strip_mask):
+            # The operand holds the encode then the strip slot words in the
+            # tables' own order (`operand_shape`): no local-word gather. The
+            # split follows the tables' shapes, which a `rebind` may change.
+            words = words[0]                      # [(B,) r W + r(r-1) Dmax]
+            lead, nenc = words.shape[:-1], enc_shift[0].size
             with jax.named_scope("exchange.encode"):
-                coded = encode_shard(loc, enc_l[0], enc_shift[0], enc_mask[0])
+                enc_w = words[..., :nenc].reshape(lead + enc_shift[0].shape)
+                coded = encode_shard(enc_w, enc_shift[0], enc_mask[0])
             with jax.named_scope("exchange.gather"):
                 allbufs = _gather_servers(coded, "servers")  # [(B,) K, W]
                 allbufs = _pad_last(allbufs)                 # zero col W
             with jax.named_scope("exchange.decode"):
                 got = take(allbufs, dec_s[0], dec_w[0])      # [(B,) r, Dmax]
-                sw = (take(loc, strip_l[0]) << strip_shift[0]) & strip_mask[0]
+                strip_w = words[..., nenc:].reshape(
+                    lead + strip_shift[0].shape)
+                sw = (strip_w << strip_shift[0]) & strip_mask[0]
                 strip = _fold(sw, -2, jax.lax.bitwise_xor)   # [(B,) r, Dmax]
                 rec = ((got ^ strip) & dec_mask[0]) >> dec_shift[0]
                 return _fold(rec, -2, jax.lax.bitwise_or)[None]  # [1, (B,) Dmax]
@@ -778,7 +836,7 @@ class FusedSparseShuffle:
             # beats a leader branch) + one coded XOR all_gather on the
             # expensive inter-rack axis.
             with jax.named_scope("exchange.encode"):
-                coded = encode_shard(rflat, enc_l[0], enc_shift[0],
+                coded = encode_shard(take(rflat, enc_l[0]), enc_shift[0],
                                      enc_mask[0])
             with jax.named_scope("exchange.gather"):
                 allbufs = _gather_servers(coded, "racks")    # [(B,) R, Wx]
@@ -838,29 +896,40 @@ class FusedSparseShuffle:
     def exchange_device(self, edge_words: np.ndarray) -> jax.Array:
         """Encode + exchange of `exchange_words`, left on the mesh.
 
+        The host gathers each server's words into the operand
+        (`operand_shape`) and places it one server per device. Flat: one
+        `np.take` of the edge words through the slot-order CSR entries
+        (`FusedSparseSchedule.enc_e`, `strip_e`) into a buffer reused per
+        payload shape, so the device program gathers only its deliveries
+        from the all_gathered buffers. Two levels: each server's local Map
+        words (`loc_e`), which phase A all_gathers within the rack.
+
         Returns the per-server padded delivery words, sharded one server
         per device: [K, (B,) Dmax] flat, [R, S, (B,) Dmax] on two levels.
         """
         s = self.sched
         tr = get_tracer()
         ew = np.ascontiguousarray(edge_words, np.uint32)
+        if ew.shape[0] != self._nnz:
+            raise ValueError(f"{ew.shape[0]} edge words for a plan over "
+                             f"{self._nnz} CSR entries")
         batched = ew.ndim == 2
         B = int(ew.shape[1]) if batched else 1
+        words = s.K * B * (s.Lmax if self._hier else self._slot_e.shape[1])
         with tr.span("phase.encode", backend="fused", B=B,
-                     nnz=int(edge_words.shape[0])):
-            # payload axis first, the sentinel zero word at index nnz
-            ew = np.concatenate([np.atleast_2d(ew.T),
-                                 np.zeros((B, 1), np.uint32)], axis=1)
-            loc = np.zeros((s.K, B, s.Lmax + 1), dtype=np.uint32)
-            loc[:, :, :s.Lmax] = np.moveaxis(ew[:, s.loc_e], 0, 1)
-            if not batched:
-                loc = loc[:, 0]                   # [K, Lmax+1]
+                     nnz=int(edge_words.shape[0]), words=words):
             if self._hier:
+                # payload axis first, the sentinel zero word at index nnz
+                ew = np.concatenate([np.atleast_2d(ew.T),
+                                     np.zeros((B, 1), np.uint32)], axis=1)
+                loc = np.zeros((s.K, B, s.Lmax + 1), dtype=np.uint32)
+                loc[:, :, :s.Lmax] = np.moveaxis(ew[:, s.loc_e], 0, 1)
                 # device (rho, s) of the (racks, servers) mesh is server
                 # rho * S + s, so the reshape is the identity placement
-                loc = loc.reshape((self.topology.racks,
-                                   self.topology.servers_per_rack)
-                                  + loc.shape[1:])
+                operand = loc.reshape(
+                    self.operand_shape(B if batched else None))
+            else:
+                operand = self._slot_words(ew)
         bits = self._schedule_bits * B
         attrs = dict(backend="fused", bits=bits, B=B, K=s.K)
         if self._hier:
@@ -875,27 +944,45 @@ class FusedSparseShuffle:
                     self._dev_tables = self._place(self._host_tables(),
                                                    self._in_specs()[1:])
             with tr.span("exchange.place"):
-                (dloc,) = self._place((loc,), self._in_specs()[:1])
+                (dop,) = self._place((operand,), self._in_specs()[:1])
             # A call shape new to the jitted exchange traces and compiles
             # (or loads from the compile cache) inside the call.
-            key = (loc.shape,) + tuple(t.shape for t in self._dev_tables)
+            key = (operand.shape,) + tuple(t.shape for t in self._dev_tables)
             first = key not in self._seen
             with tr.span("exchange.first_call" if first else "exchange.run"):
-                dev = self._fn(dloc, *self._dev_tables)
+                dev = self._fn(dop, *self._dev_tables)
                 jax.block_until_ready(dev)
             self._seen.add(key)
         reg = get_registry()
         reg.counter("exchange_calls_total",
                     "fused coded-Shuffle exchanges run").inc()
         reg.counter("exchange_host_copy_bytes_total",
-                    "bytes copied between host and chips per exchange: local "
-                    "words in, padded deliveries out").inc(loc.nbytes)
+                    "bytes copied between host and chips per exchange: word "
+                    "operand in, padded deliveries out").inc(operand.nbytes)
         reg.counter("exchange_bits_useful_total",
                     "Definition 2 load bits the schedule needs").inc(bits)
         reg.counter("exchange_bits_sent_total",
                     "bits the chips put into the exchange's collectives, "
                     "each buffer once").inc(32 * self._sent_words * B)
         return dev
+
+    def _slot_words(self, ew: np.ndarray) -> np.ndarray:
+        """The flat operand: edge words [nnz] or [nnz, B] -> [K, (B,) N]
+        slot words, CSR entry `nnz` reading the zero sentinel. Both buffers
+        are allocated on a payload shape's first call and reused, so calls
+        on one exchange must not overlap (sessions and the serving worker
+        call it from one thread)."""
+        lead = ew.shape[1:]
+        if lead not in self._bufs:
+            src = np.zeros(lead + (self._nnz + 1,), np.uint32)  # [(B,) nnz+1]
+            out = np.empty(self.operand_shape(*lead), np.uint32)
+            self._bufs[lead] = (src, out)
+        src, out = self._bufs[lead]
+        src[..., :-1] = ew.T                  # payload axis first
+        for k, idx in enumerate(self._slot_e):
+            # mode="clip" keeps `out=` unbuffered; every index is in range
+            np.take(src, idx, axis=-1, out=out[k], mode="clip")
+        return out
 
     def execute(self, edge_vals: np.ndarray) -> PlanShuffleResult:
         """Drop-in peer of `ShufflePlan.execute_coded_sparse` (batched

@@ -10,6 +10,8 @@ What the exchange reports about itself, flat and on `Topology(2, 2)`:
   jitted exchange, across `rebind`;
 * the registry counters equal their formulas, at B=1 and B=8;
 * the three device scopes reach the lowered exchange;
+* delivered words equal `ShufflePlan.execute_coded_sparse` at B=1, at B=8
+  and after a rebind that reshapes the exchange;
 * a disabled tracer leaves state and `shuffle_bits` bitwise unchanged.
 
 Runs in one subprocess so the 4-device host-platform flag never leaks into
@@ -54,8 +56,17 @@ def dims(ex):
     if ex._hier:
         d.update(R=s.R, Wx=s.Wx)
     else:
-        d.update(W=s.W)
+        d.update(W=s.W, r=s.r)
     return d
+
+
+def delivered_equal(sess, vals):
+    # the session's fused exchange against the NumPy plan executor (two
+    # levels count their bits per level, so only the words compare)
+    ref = sess.plan.execute_coded_sparse(vals, sess.tables)
+    res = sess.fused.execute(vals)
+    return bool(np.array_equal(floats_to_words(ref.values),
+                               floats_to_words(res.values)))
 
 
 def report(topo):
@@ -105,10 +116,12 @@ def report(topo):
         out["rebind_same"] = [len(tr.find("exchange.first_call")),
                               len(tr.find("exchange.run"))]
 
-        # A delta that grows W, Lmax or Dmax compiles once more, on the
-        # rebound exchange, and only on its first call.
+        # A delta that grows the device program's shapes (W and Dmax; the
+        # flat program no longer reads Lmax) compiles once more, on the
+        # rebound exchange, and only on its first call. Edges between the
+        # first and the last vertices cross servers, so they are Shuffled.
         have = set(zip(g.csr.rows.tolist(), g.csr.indices.tolist()))
-        ins = [(u, v) for u in range(12) for v in range(u + 1, 12)
+        ins = [(u, v) for u in range(12) for v in range(n - 12, n)
                if (u, v) not in have]
         upd = sess.update(EdgeDelta.for_graph(g, insert=ins))
         tr.reset()
@@ -118,14 +131,22 @@ def report(topo):
                           len(tr.find("exchange.run"))]
 
         # The device scopes reach the lowered exchange.
-        host = (jax.ShapeDtypeStruct(ex.mesh.devices.shape
-                                     + (ex.sched.Lmax + 1,), jnp.uint32),)
+        host = (jax.ShapeDtypeStruct(ex.operand_shape(), jnp.uint32),)
         args = [jax.ShapeDtypeStruct(a.shape, a.dtype,
                                      sharding=NamedSharding(ex.mesh, p))
                 for a, p in zip(host + ex._host_tables(), ex._in_specs())]
         text = ex._fn.lower(*args).as_text(debug_info=True)
         out["scopes"] = [s for s in ("exchange.encode", "exchange.gather",
                                      "exchange.decode") if s in text]
+
+        # Delivered words, before and after the reshaping rebind.
+        rng = np.random.default_rng(5)
+        ev = prog.map_edge_values(g, res.state).astype(np.float32)
+        out["parity"] = [
+            delivered_equal(sess, ev),
+            delivered_equal(sess, rng.random((g.csr.nnz, 8), np.float32)),
+            delivered_equal(upd, rng.random(upd.g.csr.nnz, np.float32)),
+            delivered_equal(upd, rng.random((upd.g.csr.nnz, 8), np.float32))]
     finally:
         obs.set_tracer(prev_tr)
         obs.set_registry(prev_reg)
@@ -188,16 +209,21 @@ def test_first_call_once_per_shape_and_after_a_reshaping_rebind(reports,
     assert rep["rebind_same"] == [0, 1]          # same shapes: reused
     d0, d1 = rep["dims"], rep["dims_updated"]
     width = "Wx" if topo == "racks2x2" else "W"
-    assert any(d0[k] != d1[k] for k in (width, "Lmax", "Dmax"))
+    assert all(d0[k] != d1[k] for k in (width, "Dmax"))
     assert rep["updated"] == [1, 1]
 
 
 def _expected(d: dict, B: int) -> dict:
-    words = (d["R"] * d["Wx"] + d["K"] * (d["Lmax"] + 1) if "Wx" in d
+    hier = "Wx" in d
+    words = (d["R"] * d["Wx"] + d["K"] * (d["Lmax"] + 1) if hier
              else d["K"] * d["W"])
+    # in: two levels, each server's local words and the sentinel; flat,
+    # its encode and strip words in slot order. out: padded deliveries.
+    operand = (d["Lmax"] + 1 if hier
+               else d["r"] * d["W"] + d["r"] * (d["r"] - 1) * d["Dmax"])
     return {"exchange_calls_total": 1,
             "exchange_host_copy_bytes_total":
-                4 * B * d["K"] * (d["Lmax"] + 1 + d["Dmax"]),
+                4 * B * d["K"] * (operand + d["Dmax"]),
             "exchange_bits_useful_total": B * d["schedule_bits"],
             "exchange_bits_sent_total": 32 * B * words}
 
@@ -219,6 +245,12 @@ def test_counters_equal_their_formulas_at_b1_and_b8(reports, topo):
 def test_device_scopes_reach_the_lowered_exchange(reports, topo):
     assert reports[topo]["scopes"] == ["exchange.encode", "exchange.gather",
                                        "exchange.decode"]
+
+
+@pytest.mark.parametrize("topo", TOPOLOGIES)
+def test_delivered_words_bitwise_at_b1_b8_and_after_a_reshaping_rebind(
+        reports, topo):
+    assert reports[topo]["parity"] == [True] * 4
 
 
 @pytest.mark.parametrize("topo", TOPOLOGIES)

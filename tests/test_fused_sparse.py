@@ -18,6 +18,14 @@ import os
 import subprocess
 import sys
 
+import numpy as np
+import pytest
+
+from repro import graphs
+from repro.core.allocation import divisible_n, er_allocation
+from repro.core.fused_shuffle import partition_plan
+from repro.core.shuffle_plan import compile_plan_csr
+
 PREAMBLE = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -95,6 +103,28 @@ g, alloc = case("er")
 out["encode_xor_kernel"] = parity(g, alloc, algo.pagerank(), iters=1,
                                   encode="xor-kernel")
 out["encode_jnp"] = parity(g, alloc, algo.pagerank(), iters=1, encode="jnp")
+
+# B=8 payload columns, then a rebind onto a plan whose partition has other
+# padded shapes (a denser graph on the same allocation), at B=1 and B=8.
+def words_equal(plan, csr, fx, vals):
+    ref = plan.execute_coded_sparse(vals, plan.edge_tables(csr, alloc))
+    return bool(np.array_equal(floats_to_words(ref.values),
+                               floats_to_words(fx.execute(vals).values)))
+
+
+rng = np.random.default_rng(2)
+g, alloc = case("er")
+plan = compile_plan_csr(g.csr, alloc)
+fx = FusedSparseShuffle(plan, g.csr, alloc)
+out["b8"] = words_equal(plan, g.csr, fx,
+                        rng.random((g.csr.nnz, 8), np.float32))
+g2 = graphs.erdos_renyi(g.n, 0.4, seed=12)
+plan2 = compile_plan_csr(g2.csr, alloc)
+fx2 = fx.rebind(plan2, g2.csr, alloc)
+out["rebind_reshaped"] = bool(fx2.operand_shape() != fx.operand_shape())
+for B in (None, 8):
+    vals = rng.random((g2.csr.nnz,) + ((B,) if B else ()), np.float32)
+    out[f"rebind_b{B or 1}"] = words_equal(plan2, g2.csr, fx2, vals)
 
 # Mid-run failure recovery rides the same CSR plans on this 8-device host.
 g, alloc = case("er")
@@ -193,6 +223,9 @@ def test_fused_sparse_word_parity_models_programs_and_spill():
     assert res["spill_has_leftovers"]          # the case really spills
     assert res["spill_pagerank"] and res["spill_sssp"]
     assert res["encode_xor_kernel"] and res["encode_jnp"]
+    assert res["b8"]                           # batched payload, flat
+    assert res["rebind_reshaped"]              # the rebind changes shapes
+    assert res["rebind_b1"] and res["rebind_b8"]
     assert res["faults_bitwise"]
     assert res["faults_recovery_bits"] > 0
 
@@ -210,3 +243,29 @@ def test_fused_engine_acceptance_and_beyond_dense_limit():
     assert res["per_device_cols"]
     assert res["per_device_deliveries"]
     assert res["tables_placed_per_device"]
+
+
+@pytest.mark.parametrize("r", [2, 3])
+def test_slot_order_entries_are_local_and_compose_the_local_tables(r):
+    """The flat exchange's host gather reads edge words through `enc_e` and
+    `strip_e`: each entry is the zero sentinel `nnz` or a CSR entry whose
+    column vertex the server Mapped, and each table is its local-index
+    table composed with `loc_e`, the sentinel carried through."""
+    K = 4
+    n = divisible_n(60, K, r)
+    g = graphs.erdos_renyi(n, 0.2, seed=4)
+    alloc = er_allocation(n, K, r)
+    s = partition_plan(compile_plan_csr(g.csr, alloc), g.csr, alloc)
+    nnz = g.csr.nnz
+    loc_z = np.concatenate([s.loc_e, np.full((K, 1), nnz)], axis=1)
+    pairs = ((s.enc_e, s.enc_l, s.enc_mask),
+             (s.strip_e, s.strip_l, s.strip_mask))
+    for ent, local, mask in pairs:
+        assert ent.dtype == np.int32 and ent.shape == local.shape
+        assert ((ent == nnz) == (local == s.Lmax)).all()
+        assert (mask[ent == nnz] == 0).all()    # sentinel slots XOR away
+        assert (ent != nnz).any()
+        for k in range(K):
+            assert np.array_equal(ent[k], loc_z[k][local[k]])
+            real = ent[k][ent[k] != nnz]
+            assert alloc.map_sets[k, g.csr.indices[real]].all()

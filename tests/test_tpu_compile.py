@@ -12,6 +12,7 @@ topology fails the tests; only a missing libtpu skips them.
 """
 import importlib.util
 import os
+import re
 
 import numpy as np
 import pytest
@@ -103,8 +104,8 @@ def test_fused_exchange_compiles_on_four_chips(topo, topology):
         plan = compile_hierarchical(g.csr, alloc, topology)
         mesh = Mesh(devs.reshape(2, 2), ("racks", "servers"))
     ex = FusedSparseShuffle(plan, g.csr, alloc, mesh, encode="xor-kernel")
-    loc_shape = mesh.devices.shape + (ex.sched.Lmax + 1,)
-    arrays = (jax.ShapeDtypeStruct(loc_shape, jnp.uint32),) + ex._host_tables()
+    arrays = ((jax.ShapeDtypeStruct(ex.operand_shape(), jnp.uint32),)
+              + ex._host_tables())
     args = [_shape(a.shape, a.dtype, NamedSharding(mesh, s))
             for a, s in zip(arrays, ex._in_specs())]
     compiled = ex._fn.lower(*args).compile()
@@ -117,3 +118,10 @@ def test_fused_exchange_compiles_on_four_chips(topo, topology):
     # where the profiler reads them
     for scope in ("exchange.encode", "exchange.gather", "exchange.decode"):
         assert f"/{scope}/" in text
+    if topology is None:
+        # The host lays the flat operand in slot order, so the only gather
+        # left reads the deliveries from the all_gathered buffers.
+        gathers = [line for line in text.splitlines()
+                   if re.search(r"= \S+ gather\(", line)]
+        assert len(gathers) == 1, gathers
+        assert "/exchange.encode/" not in gathers[0]
