@@ -560,6 +560,38 @@ def lanes_last(a: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(np.moveaxis(a, 1, -1))
 
 
+def receiver_spans(plan: ShufflePlan) -> tuple[np.ndarray, np.ndarray]:
+    """(start [K], count [K]) of each receiver's deliveries in [M] order.
+
+    Every plan constructor groups the deliveries by receiver: receiver k's
+    are `all_k[ptr[k]:ptr[k+1]]`, so delivery m sits at column m - ptr[k]
+    of k's padded row (as `partition_plan` lays the decode tables). Raises
+    ValueError on a plan that is not grouped as its `ptr` says.
+    """
+    K, M = plan.K, plan.all_k.size
+    ptr = np.asarray(plan.ptr, np.int64)
+    counts = np.diff(ptr)
+    grouped = (ptr.shape == (K + 1,) and ptr[0] == 0 and ptr[-1] == M
+               and (counts >= 0).all()
+               and all((plan.all_k[a:b] == k).all()
+                       for k, (a, b) in enumerate(zip(ptr[:-1], ptr[1:]))))
+    if not grouped:
+        raise ValueError("the plan's deliveries are not grouped by receiver "
+                         "as its ptr says (all_k[ptr[k]:ptr[k+1]] == k)")
+    return ptr[:-1], counts
+
+
+def gather_deliveries(out: np.ndarray, starts: np.ndarray,
+                      counts: np.ndarray) -> np.ndarray:
+    """Padded per-receiver deliveries [K, (B,) Dmax] -> [M] or [M, B] in the
+    plan's delivery order (`receiver_spans`): K contiguous copies into a
+    fresh C-contiguous array."""
+    res = np.empty((int(counts.sum()),) + out.shape[1:-1], out.dtype)
+    for k, (a, c) in enumerate(zip(starts.tolist(), counts.tolist())):
+        res[a:a + c] = out[k, ..., :c].T
+    return res
+
+
 def shard_encoder(encode: str, interpret: bool):
     """The exchange's per-shard encode for one `encode` backend.
 
@@ -666,6 +698,9 @@ class FusedSparseShuffle:
                 "ShufflePlan")
         self.topology = topology
         self._hier = isinstance(plan, HierarchicalPlan)
+        # The pull-back's re-index: K contiguous copies (`gather_deliveries`)
+        self._starts, self._counts = receiver_spans(
+            plan.flat if self._hier else plan)
         with get_tracer().span("exchange.partition", hier=self._hier):
             if self._hier:
                 self.hplan = plan
@@ -679,6 +714,9 @@ class FusedSparseShuffle:
                 self.sched = partition_plan(plan, csr, alloc)
                 self._schedule_bits = plan.coded_bits + plan.leftover_bits
         s = self.sched
+        if self._counts.max(initial=0) > s.Dmax:
+            raise ValueError(f"a receiver has {self._counts.max()} deliveries "
+                             f"but its padded row holds Dmax={s.Dmax}")
         # Words the chips put into the collectives per payload column, each
         # buffer counted once (a multicast): the K coded [W] buffers, or on
         # two levels the R rack buffers plus every server's phase-A words.
@@ -887,11 +925,7 @@ class FusedSparseShuffle:
             with tr.span("decode.reindex"):
                 if self._hier:
                     out = out.reshape((plan.K,) + out.shape[2:])
-                M = plan.all_k.size
-                # [M] or, batched, [M, B] (the sliced B axis sits between
-                # the two index arrays, so numpy puts it last)
-                return out[plan.all_k, ..., np.arange(M, dtype=np.int64)
-                           - plan.ptr[plan.all_k]]
+                return gather_deliveries(out, self._starts, self._counts)
 
     def exchange_device(self, edge_words: np.ndarray) -> jax.Array:
         """Encode + exchange of `exchange_words`, left on the mesh.

@@ -13,6 +13,7 @@ Runs in subprocesses so the 8-device host-platform flag never leaks into
 other tests; HOME and JAX_PLATFORMS=cpu are passed through per the ROADMAP
 note (jax device probing hangs without them).
 """
+import dataclasses
 import json
 import os
 import subprocess
@@ -23,8 +24,11 @@ import pytest
 
 from repro import graphs
 from repro.core.allocation import divisible_n, er_allocation
-from repro.core.fused_shuffle import partition_plan
-from repro.core.shuffle_plan import compile_plan_csr
+from repro.core.fused_shuffle import (FusedSparseShuffle, gather_deliveries,
+                                      partition_plan, receiver_spans)
+from repro.core.shuffle_plan import compile_hierarchical, compile_plan_csr
+from repro.graphs import EdgeDelta
+from repro.launch.mesh import Topology
 
 PREAMBLE = r"""
 import os
@@ -269,3 +273,78 @@ def test_slot_order_entries_are_local_and_compose_the_local_tables(r):
             assert np.array_equal(ent[k], loc_z[k][local[k]])
             real = ent[k][ent[k] != nnz]
             assert alloc.map_sets[k, g.csr.indices[real]].all()
+
+
+def _plan_of(kind):
+    """(plan, csr, alloc) from one plan constructor: a fresh compile, a
+    repair with server 1 failed, an edge delta, or a two-level plan."""
+    K, r = 4, 2
+    n = divisible_n(60, K, r)
+    g = graphs.erdos_renyi(n, 0.2, seed=4)
+    alloc = er_allocation(n, K, r)
+    csr, plan = g.csr, compile_plan_csr(g.csr, alloc)
+    if kind == "repair":
+        plan, alloc, _ = plan.repair(csr, alloc, (1,))
+    elif kind == "delta":
+        e = np.flatnonzero(csr.rows < csr.indices)[:3]
+        have = set(zip(csr.rows.tolist(), csr.indices.tolist()))
+        ins = [(u, v) for u in range(n) for v in range(u + 1, n)
+               if (u, v) not in have][:3]
+        delta = EdgeDelta.for_graph(
+            g, insert=ins, delete=list(zip(csr.rows[e], csr.indices[e])))
+        csr2 = csr.apply_delta(delta)
+        plan, _ = plan.apply_delta(csr, alloc, delta, csr_new=csr2)
+        csr = csr2
+    elif kind == "hier":
+        plan = compile_hierarchical(csr, alloc, Topology(2, 2))
+    return plan, csr, alloc
+
+
+@pytest.mark.parametrize("B", [None, 8])
+@pytest.mark.parametrize("kind", ["compile", "repair", "delta", "hier"])
+def test_reindex_is_per_receiver_copies_bitwise(kind, B):
+    """The pull-back re-index copies each receiver's leading `count` words
+    of its padded row; it equals the fancy index
+    `out[all_k, ..., arange(M) - ptr[all_k]]` bitwise, padding garbage."""
+    plan, _, _ = _plan_of(kind)
+    plan = plan.flat if kind == "hier" else plan
+    K, M = plan.K, plan.all_k.size
+    counts = np.diff(plan.ptr)
+    assert np.array_equal(plan.all_k, np.repeat(np.arange(K), counts))
+    starts, got = receiver_spans(plan)
+    assert np.array_equal(starts, plan.ptr[:-1])
+    assert np.array_equal(got, counts)
+    if kind == "repair":
+        assert counts[1] == 0                  # the failed server receives none
+    lead = () if B is None else (B,)
+    rng = np.random.default_rng(7)
+    out = rng.integers(0, 2**32, size=(K,) + lead + (counts.max() + 3,),
+                       dtype=np.uint32)
+    old = out[plan.all_k, ..., np.arange(M, dtype=np.int64)
+              - plan.ptr[plan.all_k]]
+    new = gather_deliveries(out, starts, counts)
+    assert new.shape == old.shape == (M,) + lead
+    assert new.dtype == np.uint32 and new.flags.c_contiguous
+    assert np.array_equal(new, old)
+
+
+@pytest.mark.parametrize("kind", ["compile", "delta", "hier"])
+def test_bind_keeps_the_receiver_spans_of_its_flat_plan(kind):
+    """`_bind` (no devices) derives the spans once, from the flat plan the
+    exchange delivers in, and every span fits the padded row."""
+    ex = object.__new__(FusedSparseShuffle)
+    ex._bind(*_plan_of(kind), None)
+    assert ex._hier == (kind == "hier")
+    assert np.array_equal(ex._starts, ex.plan.ptr[:-1])
+    assert np.array_equal(ex._counts, np.diff(ex.plan.ptr))
+    assert ex._counts.max() <= ex.sched.Dmax
+
+
+def test_bind_refuses_deliveries_not_grouped_by_receiver():
+    plan, csr, alloc = _plan_of("compile")
+    a = int(plan.ptr[1])                       # first of receiver 1
+    all_k = plan.all_k.copy()
+    all_k[[a - 1, a]] = all_k[[a, a - 1]]      # one delivery each way
+    bad = dataclasses.replace(plan, all_k=all_k)
+    with pytest.raises(ValueError, match="not grouped by receiver"):
+        object.__new__(FusedSparseShuffle)._bind(bad, csr, alloc, None)
