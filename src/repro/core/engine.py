@@ -68,7 +68,8 @@ from .bitcodec import T_BITS
 from .coded_shuffle import run_coded
 from .graph_models import Graph
 from .shuffle_plan import (HierarchicalPlan, PlanShuffleResult, ShufflePlan,
-                           compile_hierarchical, compile_plan_csr)
+                           compile_hierarchical, compile_plan_csr,
+                           resolve_topology)
 from .uncoded_shuffle import missing_pairs
 
 PLAN_MODES = ("uncoded", "coded", "coded-fast")
@@ -250,17 +251,10 @@ class CompiledEngine:
         sparse = _use_sparse(program, mode, path)
         _validate_backend_opts(backend, backend_opts)
         self.hplan = None
+        plan, topology = resolve_topology(plan, topology)
         if isinstance(plan, HierarchicalPlan):
-            if topology is not None and topology != plan.topology:
-                raise ValueError(
-                    f"topology {topology} disagrees with the plan's "
-                    f"{plan.topology}")
-            topology = plan.topology
-            if topology.is_flat:
-                plan = plan.flat              # degenerate: flat session
-            else:
-                self.hplan = plan
-                plan = plan.flat
+            self.hplan = plan
+            plan = plan.flat
         hier = topology is not None and not topology.is_flat
         if hier:
             # The hierarchical executor implements the coded sparse Shuffle

@@ -10,31 +10,18 @@ aside); latency collapses from O(#groups * #columns) transmissions to one
 collective phase - this is the hardware adaptation of the paper's shared-bus
 assumption.
 
-Two executors share that design:
-
-  * **Sparse (production path)** - `partition_plan` splits a compiled CSR
-    `ShufflePlan` per server: each device holds only its own slice of the
-    Map output (`loc_e`, the [nnz]-indexed values it Mapped, O(r nnz / K))
-    plus its encode/decode/strip tables (O(plan / K)). The host gathers
-    each server's words straight into the slot order of its encode and
-    strip tables. One iteration under `shard_map` on a ('servers',) mesh is
-    then (a) per-shard shift-mask + XOR encode through the batched
-    `kernels/xor_code` route, (b) one packed dense all_gather of uint32
-    coded words, (c) per-shard delivery gather, strip + shift-back into
-    each receiver's delivery slice. No [n, n] or O(n^2)-shaped array
-    exists anywhere on this path; `FusedSparseShuffle` jits the exchange
-    once and replays it every iteration, bit-exact against
-    `ShufflePlan.execute_coded_sparse` (unicast leftovers ride the same
-    all_gather as single-slot full-width columns).
-
-  * **Dense (small-n validation reference)** - `fused_exchange` consumes a
-    replicated [n, n] value matrix through [n, n]-indexed schedule tensors;
-    kept only to cross-check the collective layout at validation scale.
-
-The column/slot structure comes straight off the compiled `ShufflePlan`
-(compile-once) via `compile_plan_csr` - `build_schedule` accepts a `Graph`
-and never touches `.adj`, so schedule construction works on CSR-native
-graphs beyond `dense_limit`.
+`partition_plan` splits a compiled CSR `ShufflePlan` per server into
+encode/decode/strip tables (O(plan / K) each, padded to the largest
+server's). Every encode and strip slot names its word by CSR entry, so the
+host gathers each server's edge words straight into the slot order of its
+tables. One iteration under `shard_map` on a ('servers',) mesh is then (a)
+per-shard shift-mask + XOR encode through the batched `kernels/xor_code`
+route, (b) one packed dense all_gather of uint32 coded words, (c) per-shard
+delivery gather, strip + shift-back into each receiver's delivery slice. No
+[n, n] or O(n^2)-shaped array exists anywhere on this path;
+`FusedSparseShuffle` jits the exchange once and replays it every iteration,
+bit-exact against `ShufflePlan.execute_coded_sparse` (unicast leftovers ride
+the same all_gather as single-slot full-width columns).
 
 Word format: one uint32 per coded column and slot, in *codec bit order*
 (`bitcodec.floats_to_words`), so segment s of a value travels left-aligned
@@ -49,9 +36,11 @@ all_gather of rack-level packed buffers on the expensive 'racks' axis -
 every rack encodes from its phase-A union buffer (replicated within the
 rack, so recompute beats a leader branch), and each server decodes its own
 delivery slice from the rack buffers plus direct intra-rack gathers.
-Delivered words stay bitwise equal to the flat path (`partition_plan` /
-`FusedSparseShuffle` accept a `Topology` and degenerate to the single-level
-exchange on `Topology.flat(K)`).
+`partition_hierarchical` lays out the rack-level plan with the same code as
+the flat partition (`_coded_level`); its slots name words by phase-A buffer
+position instead of CSR entry. Delivered words stay bitwise equal to the
+flat path (`partition_plan` / `FusedSparseShuffle` accept a `Topology` and
+degenerate to the single-level exchange on `Topology.flat(K)`).
 """
 from __future__ import annotations
 
@@ -69,9 +58,9 @@ from ..obs import get_tracer
 from ..obs.metrics import get_registry
 from .allocation import Allocation
 from .bitcodec import floats_to_words, words_to_floats
-from .graph_models import CSR, Graph
+from .graph_models import CSR
 from .shuffle_plan import (HierarchicalPlan, PlanShuffleResult, ShufflePlan,
-                           _rack_first_mapper, _run_ranks, compile_plan_csr)
+                           _rack_first_mapper, _run_ranks, resolve_topology)
 
 FULL_MASK = np.uint32(0xFFFFFFFF)
 
@@ -91,9 +80,108 @@ def _sender_layout(plan: ShufflePlan) -> tuple[np.ndarray, np.ndarray]:
     return colpos, ncols
 
 
-# ---------------------------------------------------------------------------
-# Sparse multi-device path (production)
-# ---------------------------------------------------------------------------
+def _per_receiver(stream: ShufflePlan, rows: np.ndarray, fill) -> np.ndarray:
+    """[M, ...] rows in the delivery order of `stream` -> [K, Dmax, ...]
+    padded per-receiver rows (delivery m at column m - ptr[k] of its
+    receiver k's row, `fill` in the padding; Dmax the largest receiver's
+    delivery count)."""
+    kk = stream.all_k
+    Dmax = max(int(np.diff(stream.ptr).max()), 1)
+    out = np.full((stream.K, Dmax) + rows.shape[1:], fill, rows.dtype)
+    out[kk, np.arange(kk.size, dtype=np.int64) - stream.ptr[kk]] = rows
+    return out
+
+
+def _coded_level(plan: ShufflePlan, stream: ShufflePlan, holders: np.ndarray,
+                 tables, name, zero: int, cov, left):
+    """Lay out one coded level of a plan for the fused exchange.
+
+    `plan` is the level's schedule: the flat plan, or a `HierarchicalPlan`'s
+    rack-level `inter` (racks as senders). Sender s holds the values of the
+    vertices in `holders[s]` (the level's `map_sets`); `tables` are the
+    level's CSR edge tables. A slot names its word through
+    ``name(who, j, e, role)``: the word of vertex j's value, CSR entry e, as
+    `who` holds it; `role` ("sender {} schedules", ...) words the error when
+    `who` does not hold it. `zero` names the guaranteed-zero word.
+
+    Unicast leftovers go to the smallest sender holding their column vertex,
+    appended after its coded columns as single-slot full-width columns.
+    `cov` = (rows, pairs) and `left` = (rows, leftovers) place the covered
+    pairs and leftovers among the deliveries of `stream` (the flat plan),
+    whose per-receiver rows (`_per_receiver`) the decode tables are.
+
+    Returns (W, encode, decode): the per-sender buffer width; the
+    [senders, W, r] word, shift and mask tables; and the [K, Dmax, r]
+    sender, buffer column (W = zero column), mask and shift tables followed
+    by the [K, Dmax, r, r-1] strip word, shift and mask tables.
+    """
+    r, Pn = plan.r, plan.pair_k.size
+    nstrip = max(r - 1, 0)
+    colpos, ncols = _sender_layout(plan)
+
+    lsender = np.argmax(holders[:, plan.left_j], axis=0)
+    if not holders[lsender, plan.left_j].all():
+        raise RuntimeError("leftover value has no Mapping server")
+    lorder = np.argsort(lsender, kind="stable")
+    _, lrank = _run_ranks(lsender[lorder])
+    leftw = np.empty(lsender.size, dtype=np.int64)
+    leftw[lorder] = ncols[lsender[lorder]] + lrank
+    nleft = np.bincount(lsender, minlength=ncols.size)
+    W = max(int((ncols + nleft).max()), 1)
+
+    # --- encode tables: valid plan slots + leftover slots, per sender ---
+    enc = np.full((ncols.size, W, r), zero, dtype=np.int32)
+    enc_shift = np.zeros((ncols.size, W, r), dtype=np.uint32)
+    enc_mask = np.zeros((ncols.size, W, r), dtype=np.uint32)
+    cs, sl = np.nonzero(plan.slot_pair < Pn)
+    p = plan.slot_pair[cs, sl]
+    snd = plan.col_sender[cs]
+    enc[snd, colpos[cs], sl] = name(snd, plan.pair_j[p], tables.pair_e[p],
+                                    "sender {} schedules")
+    enc_shift[snd, colpos[cs], sl] = plan.slot_shift[cs, sl]
+    enc_mask[snd, colpos[cs], sl] = plan.slot_mask[cs, sl]
+    enc[lsender, leftw, 0] = name(lsender, plan.left_j, tables.left_e,
+                                  "sender {} unicasts")
+    enc_mask[lsender, leftw, 0] = FULL_MASK          # full word, shift 0
+
+    # --- decode rows, first in the stream's flat delivery order ---
+    M = stream.all_k.size
+    f_s = np.zeros((M, r), dtype=np.int32)
+    f_w = np.full((M, r), W, dtype=np.int32)         # W = zero column
+    f_mask = np.zeros((M, r), dtype=np.uint32)
+    f_shift = np.zeros((M, r), dtype=np.uint32)
+    f_x = np.full((M, r, nstrip), zero, dtype=np.int32)
+    f_ssh = np.zeros((M, r, nstrip), dtype=np.uint32)
+    f_smk = np.zeros((M, r, nstrip), dtype=np.uint32)
+    rows, pc = cov
+    c, slot = plan.pair_col[pc], plan.pair_slot[pc]  # [Pc, r]
+    f_s[rows] = plan.col_sender[c]
+    f_w[rows] = colpos[c]
+    f_mask[rows] = plan.slot_mask[c, slot]
+    f_shift[rows] = plan.seg_shift
+    if nstrip:
+        # the other slots of each segment's column, which its receiver strips
+        ar = np.broadcast_to(np.arange(r), (rows.size, r, r))
+        others = ar[ar != slot[..., None]].reshape(rows.size, r, nstrip)
+        c3 = np.broadcast_to(c[:, :, None], others.shape)
+        sp = plan.slot_pair[c3, others]              # [Pc, r, r-1]
+        valid = sp < Pn
+        who = np.broadcast_to(plan.pair_k[pc][:, None, None], sp.shape)
+        sx = np.full(sp.shape, zero, dtype=np.int32)
+        sx[valid] = name(who[valid], plan.pair_j[sp[valid]],
+                         tables.pair_e[sp[valid]], "receiver {} must strip")
+        f_x[rows] = sx
+        f_ssh[rows] = plan.slot_shift[c3, others]
+        f_smk[rows] = plan.slot_mask[c3, others]
+    rows, pl = left
+    f_s[rows, 0] = lsender[pl]
+    f_w[rows, 0] = leftw[pl]
+    f_mask[rows, 0] = FULL_MASK                      # full word, shift 0
+
+    decode = tuple(_per_receiver(stream, a, fill) for a, fill in (
+        (f_s, 0), (f_w, W), (f_mask, 0), (f_shift, 0), (f_x, zero),
+        (f_ssh, 0), (f_smk, 0)))
+    return W, (enc, enc_shift, enc_mask), decode
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,23 +189,22 @@ class FusedSparseSchedule:
     """Per-server partition of a compiled CSR plan (all arrays plan-sized).
 
     Device k's shard (row k of every array) is everything it needs for one
-    coded Shuffle: `loc_e` selects the [nnz] edge values it Mapped (column
-    vertex in M_k - O(r nnz / K) entries), the `enc_*` tables lay its coded
-    columns (+ its unicast leftovers, as single-slot full-width columns)
-    into a [W]-word buffer, and the `dec_*`/`strip_*` tables recover its
-    delivery slice from the all_gathered [K, W] buffer matrix.
+    coded Shuffle: the `enc_*` tables lay its coded columns (+ its unicast
+    leftovers, as single-slot full-width columns) into a [W]-word buffer,
+    and the `dec_*`/`strip_*` tables recover its delivery slice from the
+    all_gathered [K, W] buffer matrix.
 
-    `enc_e` and `strip_e` are `enc_l` and `strip_l` composed with `loc_e`:
-    the CSR entry of every encode and strip slot. The flat exchange's host
-    side gathers the edge words through them straight into slot order, so
-    its device program reads every encode and strip word in place; the
-    local-index tables stay for the two-level partition and the locality
-    checks.
+    `enc_e` and `strip_e` name the word of every encode and strip slot by
+    its CSR entry, an entry whose column vertex server k Mapped. The
+    exchange's host side gathers the edge words through them straight into
+    slot order, so its device program reads every encode and strip word in
+    place. `Lmax`, the most CSR entries whose column vertex one server
+    Mapped, sizes a server's share of the Map output.
 
-    Sentinels: local index `Lmax` and CSR entry `nnz` are guaranteed-zero
-    words; buffer column `W` is a guaranteed-zero column (padded after the
-    all_gather); masks of sentinel slots are 0, so they OR/XOR away -
-    encode and decode are plain shift-mask pipelines with no control flow.
+    Sentinels: CSR entry `nnz` is a guaranteed-zero word; buffer column `W`
+    is a guaranteed-zero column (padded after the all_gather); masks of
+    sentinel slots are 0, so they OR/XOR away - encode and decode are plain
+    shift-mask pipelines with no control flow.
     """
 
     K: int
@@ -125,19 +212,16 @@ class FusedSparseSchedule:
     W: int                        # per-sender buffer width (words)
     Lmax: int                     # max local-value count over servers
     Dmax: int                     # max delivery count over receivers
-    loc_e: np.ndarray             # [K, Lmax] int64 CSR entry (nnz = zero pad)
-    enc_l: np.ndarray             # [K, W, r] int32 local index (Lmax = zero)
+    enc_e: np.ndarray             # [K, W, r] int32 CSR entry (nnz = zero)
     enc_shift: np.ndarray         # [K, W, r] uint32 segment left-shift
     enc_mask: np.ndarray          # [K, W, r] uint32 segment keep-mask
     dec_s: np.ndarray             # [K, Dmax, r] int32 sender of segment t
     dec_w: np.ndarray             # [K, Dmax, r] int32 buffer column (W = zero)
     dec_mask: np.ndarray          # [K, Dmax, r] uint32 own-slot keep-mask
     dec_shift: np.ndarray         # [K, Dmax, r] uint32 shift back into place
-    strip_l: np.ndarray           # [K, Dmax, r, r-1] int32 local index
+    strip_e: np.ndarray           # [K, Dmax, r, r-1] int32 CSR entry (nnz = zero)
     strip_shift: np.ndarray       # [K, Dmax, r, r-1] uint32
     strip_mask: np.ndarray        # [K, Dmax, r, r-1] uint32
-    enc_e: np.ndarray             # [K, W, r] int32 CSR entry (nnz = zero)
-    strip_e: np.ndarray           # [K, Dmax, r, r-1] int32 CSR entry (nnz = zero)
 
 
 def partition_plan(plan: ShufflePlan | HierarchicalPlan, csr: CSR,
@@ -153,156 +237,30 @@ def partition_plan(plan: ShufflePlan | HierarchicalPlan, csr: CSR,
     routes to `partition_hierarchical`; `Topology.flat(K)` degenerates to
     the single-level partition of the plan's flat schedule.
     """
+    plan, _ = resolve_topology(plan, topology)
     if isinstance(plan, HierarchicalPlan):
-        if topology is not None and topology != plan.topology:
-            raise ValueError(
-                f"topology {topology} disagrees with the plan's "
-                f"{plan.topology}")
-        if not plan.topology.is_flat:
-            return partition_hierarchical(plan, csr, alloc)
-        plan = plan.flat
-    elif topology is not None and not topology.is_flat:
-        raise ValueError(
-            "a non-flat Topology needs a HierarchicalPlan "
-            "(core.shuffle_plan.compile_hierarchical), got a flat "
-            "ShufflePlan")
+        return partition_hierarchical(plan, csr, alloc)
     plan._require_schedule()
-    tables = plan.edge_tables(csr, alloc)     # locates edges + validates
-    K, r = plan.K, plan.r
-    C = plan.col_sender.size
-    Pn = plan.pair_k.size
-    L = plan.left_k.size
-    nstrip = max(r - 1, 0)
-
-    colpos, ncols = _sender_layout(plan)
-
-    # Leftover layout: sender = smallest mapper of the column vertex,
-    # appended after that sender's coded columns (stable (k, i, j) order).
-    if L:
-        lsender = np.argmax(alloc.map_sets[:, plan.left_j], axis=0)
-        if not alloc.map_sets[lsender, plan.left_j].all():
-            raise RuntimeError("leftover value has no Mapping server")
-        lorder = np.argsort(lsender, kind="stable")
-        _, lrank = _run_ranks(lsender[lorder])
-        leftw = np.empty(L, dtype=np.int64)
-        leftw[lorder] = ncols[lsender[lorder]] + lrank
-        nleft = np.bincount(lsender, minlength=K)
-    else:
-        lsender = np.zeros(0, dtype=np.int64)
-        leftw = np.zeros(0, dtype=np.int64)
-        nleft = np.zeros(K, dtype=np.int64)
-    W = max(int((ncols + nleft).max()), 1)
-
-    # Per-server local Map slices: CSR entries whose column vertex the
-    # server Mapped (it can recompute exactly these values locally).
-    member = alloc.map_sets[:, csr.indices]             # [K, nnz] bool
-    counts = member.sum(axis=1)
-    Lmax = max(int(counts.max()), 1)
-    loc_e = np.full((K, Lmax), csr.nnz, dtype=np.int64)  # nnz = zero pad
-
-    # --- encode tables: valid plan slots + leftover slots, per sender ---
-    enc_l = np.full((K, W, r), Lmax, dtype=np.int32)     # Lmax = zero word
-    enc_shift = np.zeros((K, W, r), dtype=np.uint32)
-    enc_mask = np.zeros((K, W, r), dtype=np.uint32)
-    cs, sl = np.nonzero(plan.slot_pair < Pn) if C else (
-        np.zeros(0, np.int64), np.zeros(0, np.int64))
-    e_of_slot = tables.pair_e[plan.slot_pair[cs, sl]] if cs.size else cs
-    s_of_slot = plan.col_sender[cs] if cs.size else cs
-
-    # --- decode tables, first in flat (k, i, j) delivery order ---
-    M = plan.all_k.size
-    f_s = np.zeros((M, r), dtype=np.int32)
-    f_w = np.full((M, r), W, dtype=np.int32)             # W = zero column
-    f_mask = np.zeros((M, r), dtype=np.uint32)
-    f_shift = np.zeros((M, r), dtype=np.uint32)
-    f_sl = np.full((M, r, nstrip), Lmax, dtype=np.int32)
-    f_ssh = np.zeros((M, r, nstrip), dtype=np.uint32)
-    f_smk = np.zeros((M, r, nstrip), dtype=np.uint32)
-    if Pn:
-        mpos = plan.pos_covered
-        c, slot = plan.pair_col, plan.pair_slot          # [P, r]
-        f_s[mpos] = plan.col_sender[c]
-        f_w[mpos] = colpos[c]
-        f_mask[mpos] = plan.slot_mask[c, slot]
-        f_shift[mpos] = np.broadcast_to(plan.seg_shift[None, :], (Pn, r))
-        if nstrip:
-            ar = np.broadcast_to(np.arange(r)[None, None, :], (Pn, r, r))
-            others = ar[~(ar == slot[..., None])].reshape(Pn, r, nstrip)
-            c3 = np.broadcast_to(c[:, :, None], (Pn, r, nstrip))
-            sp = plan.slot_pair[c3, others]              # [P, r, r-1]
-            svalid = sp < Pn
-            f_ssh[mpos] = plan.slot_shift[c3, others]
-            f_smk[mpos] = plan.slot_mask[c3, others]
-            e_strip = tables.pair_e[np.minimum(sp, max(Pn - 1, 0))]
-    if L:
-        f_s[plan.pos_left, 0] = lsender
-        f_w[plan.pos_left, 0] = leftw
-        f_mask[plan.pos_left, 0] = FULL_MASK             # full word, shift 0
-
-    # --- per-server local index conversions (one vectorized pass each) ---
-    for k in range(K):
-        lset = np.flatnonzero(member[k])
-        loc_e[k, :lset.size] = lset
-        lpos = np.cumsum(member[k]) - 1                  # entry -> local idx
-        if cs.size:
-            m = s_of_slot == k                           # encode slots k sends
-            if not member[k][e_of_slot[m]].all():
-                raise RuntimeError(f"sender {k} schedules a value it "
-                                   "did not Map")
-            enc_l[k, colpos[cs[m]], sl[m]] = lpos[e_of_slot[m]]
-            enc_shift[k, colpos[cs[m]], sl[m]] = plan.slot_shift[cs[m], sl[m]]
-            enc_mask[k, colpos[cs[m]], sl[m]] = plan.slot_mask[cs[m], sl[m]]
-        if L:
-            m = lsender == k                             # leftovers k unicasts
-            if not member[k][tables.left_e[m]].all():
-                raise RuntimeError(f"sender {k} unicasts a value it "
-                                   "did not Map")
-            enc_l[k, leftw[m], 0] = lpos[tables.left_e[m]]
-            enc_mask[k, leftw[m], 0] = FULL_MASK         # full word, shift 0
-        if Pn and nstrip:
-            m = plan.pair_k == k                         # strips k recomputes
-            li = np.where(svalid[m], lpos[e_strip[m]], Lmax)
-            if not (member[k][e_strip[m]] | ~svalid[m]).all():
-                raise RuntimeError(f"receiver {k} must strip a value it "
-                                   "did not Map")
-            f_sl[plan.pos_covered[m]] = li.astype(np.int32)
-
-    # --- scatter the flat decode tables into per-receiver padded rows ---
-    dcount = np.diff(plan.ptr)
-    Dmax = max(int(dcount.max()) if K else 0, 1)
-    kk = plan.all_k
-    dd = np.arange(M, dtype=np.int64) - plan.ptr[kk]
-    dec_s = np.zeros((K, Dmax, r), dtype=np.int32)
-    dec_w = np.full((K, Dmax, r), W, dtype=np.int32)
-    dec_mask = np.zeros((K, Dmax, r), dtype=np.uint32)
-    dec_shift = np.zeros((K, Dmax, r), dtype=np.uint32)
-    strip_l = np.full((K, Dmax, r, nstrip), Lmax, dtype=np.int32)
-    strip_shift = np.zeros((K, Dmax, r, nstrip), dtype=np.uint32)
-    strip_mask = np.zeros((K, Dmax, r, nstrip), dtype=np.uint32)
-    dec_s[kk, dd] = f_s
-    dec_w[kk, dd] = f_w
-    dec_mask[kk, dd] = f_mask
-    dec_shift[kk, dd] = f_shift
-    strip_l[kk, dd] = f_sl
-    strip_shift[kk, dd] = f_ssh
-    strip_mask[kk, dd] = f_smk
-
-    # --- slot-order CSR entries: local indices composed with loc_e ---
     if csr.nnz >= np.iinfo(np.int32).max:
         raise ValueError(f"nnz={csr.nnz} does not fit int32 CSR entries")
-    loc_z = np.concatenate([loc_e, np.full((K, 1), csr.nnz, np.int64)],
-                           axis=1).astype(np.int32)      # [Lmax] -> nnz
+    tables = plan.edge_tables(csr, alloc)     # locates edges + validates
 
-    def entries(local):
-        flat = np.take_along_axis(loc_z, local.reshape(K, -1), axis=1)
-        return flat.reshape(local.shape)
+    def entry(who, j, e, role):
+        # A slot's word is its CSR entry, which `who` must have Mapped.
+        bad = ~alloc.map_sets[who, csr.indices[e]]
+        if bad.any():
+            raise RuntimeError(f"{role.format(int(who[bad].min()))} a value "
+                               "it did not Map")
+        return e
 
-    return FusedSparseSchedule(
-        K=K, r=r, W=W, Lmax=Lmax, Dmax=Dmax, loc_e=loc_e,
-        enc_l=enc_l, enc_shift=enc_shift, enc_mask=enc_mask,
-        dec_s=dec_s, dec_w=dec_w, dec_mask=dec_mask, dec_shift=dec_shift,
-        strip_l=strip_l, strip_shift=strip_shift, strip_mask=strip_mask,
-        enc_e=entries(enc_l), strip_e=entries(strip_l))
+    W, enc, dec = _coded_level(
+        plan, plan, alloc.map_sets, tables, entry, csr.nnz,
+        (plan.pos_covered, np.arange(plan.pair_k.size)),
+        (plan.pos_left, np.arange(plan.left_k.size)))
+    in_deg = np.bincount(csr.indices, minlength=alloc.n)
+    Lmax = max(int((alloc.map_sets @ in_deg).max()), 1)
+    return FusedSparseSchedule(plan.K, plan.r, W, Lmax, dec[0].shape[1],
+                               *enc, *dec)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -356,8 +314,7 @@ def partition_hierarchical(hplan: HierarchicalPlan, csr: CSR,
     """
     flat, inter, topo = hplan.flat, hplan.inter, hplan.topology
     R, S = topo.racks, topo.servers_per_rack
-    K, rr = flat.K, inter.r
-    nstrip = max(rr - 1, 0)
+    K = flat.K
     flat._require_schedule()
     inter._require_schedule()
     ft = flat.edge_tables(csr, alloc)           # locates + validates
@@ -375,7 +332,7 @@ def partition_hierarchical(hplan: HierarchicalPlan, csr: CSR,
     blk = Lmax + 1
     ZERO = Lmax                                 # rflat[Lmax] == 0 pad word
 
-    def rfidx(rack, j, e):
+    def rfidx(rack, j, e, role=None):
         """Phase-A buffer position of vertex j's value (CSR entry e) as
         held by `rack`'s designated source server."""
         if not has[rack, j].all():
@@ -388,129 +345,32 @@ def partition_hierarchical(hplan: HierarchicalPlan, csr: CSR,
                                "assigned value")
         return (off * blk + lpos_all[src, e]).astype(np.int32)
 
-    # --- rack-level sender layout + encode tables (one row per rack) ---
-    colpos, ncols = _sender_layout(inter)
-    Px = inter.pair_k.size
-    Lx = inter.left_k.size
-    if Lx:
-        lsender = np.argmax(has[:, inter.left_j], axis=0)
-        if not has[lsender, inter.left_j].all():
-            raise RuntimeError("rack-level leftover has no Mapping rack")
-        lorder = np.argsort(lsender, kind="stable")
-        _, lrank = _run_ranks(lsender[lorder])
-        leftw = np.empty(Lx, dtype=np.int64)
-        leftw[lorder] = ncols[lsender[lorder]] + lrank
-        nleft = np.bincount(lsender, minlength=R)
-    else:
-        lsender = np.zeros(0, dtype=np.int64)
-        leftw = np.zeros(0, dtype=np.int64)
-        nleft = np.zeros(R, dtype=np.int64)
-    Wx = max(int((ncols + nleft).max()), 1)
-
-    enc_l = np.full((R, Wx, rr), ZERO, dtype=np.int32)
-    enc_shift = np.zeros((R, Wx, rr), dtype=np.uint32)
-    enc_mask = np.zeros((R, Wx, rr), dtype=np.uint32)
-    if inter.col_sender.size:
-        cs, sl = np.nonzero(inter.slot_pair < Px)
-        p = inter.slot_pair[cs, sl]
-        sr = inter.col_sender[cs]               # sending rack per slot
-        enc_l[sr, colpos[cs], sl] = rfidx(sr, inter.pair_j[p], xt.pair_e[p])
-        enc_shift[sr, colpos[cs], sl] = inter.slot_shift[cs, sl]
-        enc_mask[sr, colpos[cs], sl] = inter.slot_mask[cs, sl]
-    if Lx:
-        enc_l[lsender, leftw, 0] = rfidx(lsender, inter.left_j, xt.left_e)
-        enc_mask[lsender, leftw, 0] = FULL_MASK
-
-    # --- decode tables, first in flat (k, i, j) delivery order ---
-    M = flat.all_k.size
-    f_rk = np.zeros((M, rr), dtype=np.int32)
-    f_w = np.full((M, rr), Wx, dtype=np.int32)
-    f_mask = np.zeros((M, rr), dtype=np.uint32)
-    f_shift = np.zeros((M, rr), dtype=np.uint32)
-    f_sf = np.full((M, rr, nstrip), ZERO, dtype=np.int32)
-    f_ssh = np.zeros((M, rr, nstrip), dtype=np.uint32)
-    f_smk = np.zeros((M, rr, nstrip), dtype=np.uint32)
-    f_dl = np.full(M, ZERO, dtype=np.int32)
-    f_dm = np.zeros(M, dtype=np.uint32)
-
-    d_rho = hplan.rack_of[flat.all_k]
-    intra = hplan.inter_pos < 0
-    if intra.any():
-        f_dl[intra] = rfidx(d_rho[intra], flat.all_j[intra], ft.all_e[intra])
-        f_dm[intra] = FULL_MASK
-
     # Inter deliveries: invert the inter plan's pos_covered/pos_left to
     # find which covered pair / leftover each flat delivery resolves to.
     Mx = inter.all_k.size
     kind_left = np.zeros(Mx, dtype=bool)
     kind_left[inter.pos_left] = True
     idx_in = np.empty(Mx, dtype=np.int64)
-    idx_in[inter.pos_covered] = np.arange(Px, dtype=np.int64)
-    idx_in[inter.pos_left] = np.arange(Lx, dtype=np.int64)
+    idx_in[inter.pos_covered] = np.arange(inter.pair_k.size, dtype=np.int64)
+    idx_in[inter.pos_left] = np.arange(inter.left_k.size, dtype=np.int64)
+    intra = hplan.inter_pos < 0
     ms = np.flatnonzero(~intra)
     q = hplan.inter_pos[ms]
     is_l = kind_left[q]
-    mc, pc = ms[~is_l], idx_in[q[~is_l]]
-    if mc.size:
-        c, slot = inter.pair_col[pc], inter.pair_slot[pc]   # [Pc, rr]
-        f_rk[mc] = inter.col_sender[c]
-        f_w[mc] = colpos[c]
-        f_mask[mc] = inter.slot_mask[c, slot]
-        f_shift[mc] = np.broadcast_to(inter.seg_shift[None, :],
-                                      (mc.size, rr))
-        if nstrip:
-            ar = np.broadcast_to(np.arange(rr)[None, None, :],
-                                 (mc.size, rr, rr))
-            others = ar[~(ar == slot[..., None])].reshape(mc.size, rr,
-                                                          nstrip)
-            c3 = np.broadcast_to(c[:, :, None], (mc.size, rr, nstrip))
-            sp = inter.slot_pair[c3, others]
-            svalid = sp < Px
-            if svalid.any():
-                spv = sp[svalid]
-                rho3 = np.broadcast_to(d_rho[mc][:, None, None],
-                                       sp.shape)[svalid]
-                fill = np.full(sp.shape, ZERO, dtype=np.int32)
-                fill[svalid] = rfidx(rho3, inter.pair_j[spv],
-                                     xt.pair_e[spv])
-                f_sf[mc] = fill
-            f_ssh[mc] = inter.slot_shift[c3, others]
-            f_smk[mc] = inter.slot_mask[c3, others]
-    ml, pl = ms[is_l], idx_in[q[is_l]]
-    if ml.size:
-        f_rk[ml, 0] = lsender[pl]
-        f_w[ml, 0] = leftw[pl]
-        f_mask[ml, 0] = FULL_MASK               # full word, shift 0
+    Wx, enc, dec = _coded_level(
+        inter, flat, has, xt, rfidx, ZERO,
+        (ms[~is_l], idx_in[q[~is_l]]), (ms[is_l], idx_in[q[is_l]]))
 
-    # --- scatter into per-receiver padded rows (flat per-server CSR) ---
-    Dmax = max(int(np.diff(flat.ptr).max()) if K else 0, 1)
-    kk = flat.all_k
-    dd = np.arange(M, dtype=np.int64) - flat.ptr[kk]
-    dec_rk = np.zeros((K, Dmax, rr), dtype=np.int32)
-    dec_w = np.full((K, Dmax, rr), Wx, dtype=np.int32)
-    dec_mask = np.zeros((K, Dmax, rr), dtype=np.uint32)
-    dec_shift = np.zeros((K, Dmax, rr), dtype=np.uint32)
-    strip_f = np.full((K, Dmax, rr, nstrip), ZERO, dtype=np.int32)
-    strip_shift = np.zeros((K, Dmax, rr, nstrip), dtype=np.uint32)
-    strip_mask = np.zeros((K, Dmax, rr, nstrip), dtype=np.uint32)
-    direct_l = np.full((K, Dmax), ZERO, dtype=np.int32)
-    direct_mask = np.zeros((K, Dmax), dtype=np.uint32)
-    dec_rk[kk, dd] = f_rk
-    dec_w[kk, dd] = f_w
-    dec_mask[kk, dd] = f_mask
-    dec_shift[kk, dd] = f_shift
-    strip_f[kk, dd] = f_sf
-    strip_shift[kk, dd] = f_ssh
-    strip_mask[kk, dd] = f_smk
-    direct_l[kk, dd] = f_dl
-    direct_mask[kk, dd] = f_dm
-
+    # Intra-only deliveries: a direct word from the receiving rack's
+    # phase-A buffer.
+    f_dl = np.full(flat.all_k.size, ZERO, dtype=np.int32)
+    f_dm = np.zeros(flat.all_k.size, dtype=np.uint32)
+    f_dl[intra] = rfidx(hplan.rack_of[flat.all_k[intra]], flat.all_j[intra],
+                        ft.all_e[intra])
+    f_dm[intra] = FULL_MASK
     return FusedHierarchicalSchedule(
-        K=K, R=R, S=S, rr=rr, Wx=Wx, Lmax=Lmax, Dmax=Dmax, loc_e=loc_e,
-        enc_l=enc_l, enc_shift=enc_shift, enc_mask=enc_mask,
-        dec_rk=dec_rk, dec_w=dec_w, dec_mask=dec_mask, dec_shift=dec_shift,
-        strip_f=strip_f, strip_shift=strip_shift, strip_mask=strip_mask,
-        direct_l=direct_l, direct_mask=direct_mask)
+        K, R, S, inter.r, Wx, Lmax, dec[0].shape[1], loc_e, *enc, *dec,
+        _per_receiver(flat, f_dl, ZERO), _per_receiver(flat, f_dm, 0))
 
 
 ENCODE_BACKENDS = ("xor-ref", "xor-kernel", "jnp")
@@ -683,20 +543,7 @@ class FusedSparseShuffle:
         (or no topology) degenerates to the single-level exchange on the
         plan's flat schedule.
         """
-        if isinstance(plan, HierarchicalPlan):
-            if topology is not None and topology != plan.topology:
-                raise ValueError(
-                    f"topology {topology} disagrees with the plan's "
-                    f"{plan.topology}")
-            topology = plan.topology
-            if topology.is_flat:
-                plan = plan.flat
-        elif topology is not None and not topology.is_flat:
-            raise ValueError(
-                "a non-flat Topology needs a HierarchicalPlan "
-                "(core.shuffle_plan.compile_hierarchical), got a flat "
-                "ShufflePlan")
-        self.topology = topology
+        plan, self.topology = resolve_topology(plan, topology)
         self._hier = isinstance(plan, HierarchicalPlan)
         # The pull-back's re-index: K contiguous copies (`gather_deliveries`)
         self._starts, self._counts = receiver_spans(
@@ -1033,141 +880,3 @@ class FusedSparseShuffle:
                 * (edge_vals.shape[1] if edge_vals.ndim == 2 else 1))
         return PlanShuffleResult(plan.all_k, plan.all_i, plan.all_j,
                                  values, plan.ptr, bits, plan.n)
-
-
-def run_fused_sparse(g: Graph, edge_vals: np.ndarray, alloc: Allocation,
-                     mesh: Mesh | None = None, *, encode: str = "xor-ref",
-                     interpret: bool | None = None) -> PlanShuffleResult:
-    """Convenience one-shot: compile + partition + one sparse exchange."""
-    plan = compile_plan_csr(g.csr, alloc, validate=False)
-    ex = FusedSparseShuffle(plan, g.csr, alloc, mesh, encode=encode,
-                            interpret=interpret)
-    return ex.execute(edge_vals)
-
-
-# ---------------------------------------------------------------------------
-# Dense small-n validation reference
-# ---------------------------------------------------------------------------
-
-
-def build_schedule(g: Graph, alloc: Allocation,
-                   plan: ShufflePlan | None = None):
-    """Static (graph-dependent, data-independent) dense-reference schedule.
-
-    Compiles the ShufflePlan once - adjacency-free via `compile_plan_csr`,
-    so a CSR-native graph beyond `dense_limit` never materializes [n, n] -
-    and lays its columns out per sender, padded to a common buffer length
-    so the all_gather is dense. Returns numpy index tensors consumed by the
-    jitted dense exchange (covered pairs only; leftovers are a sparse-path
-    concern - see `partition_plan`).
-    """
-    K, r = alloc.K, alloc.r
-    if plan is None:
-        plan = compile_plan_csr(g.csr, alloc, validate=False)
-    # Per-sender column order comes from the one shared layout rule
-    # (`_sender_layout`), so the dense reference and the sparse partition
-    # can never disagree on buffer positions.
-    colpos, ncols = _sender_layout(plan)
-    per_s: list[list[int]] = [[0] * int(ncols[s]) for s in range(K)]
-    for c in range(plan.col_sender.size):
-        per_s[int(plan.col_sender[c])][int(colpos[c])] = c
-    width = int(ncols.max()) if ncols.size else 0
-
-    P_pairs = plan.pair_k.size
-    # Encode tensors: for slot t of server s, the XOR of values v[i,j] over
-    # receivers. We express it as up-to-r (i, j) index pairs (-1 padded).
-    enc_idx = np.full((K, width, r, 2), -1, dtype=np.int32)
-    for s in range(K):
-        for t, c in enumerate(per_s[s]):
-            for sl in range(r):
-                p = int(plan.slot_pair[c, sl])
-                if p == P_pairs:          # sentinel: empty slot
-                    continue
-                enc_idx[s, t, sl] = (plan.pair_i[p], plan.pair_j[p])
-    # Decode map: receiver k strips every other member's value from the slot.
-    # For each (sender s, slot t) useful to k: target (i, j) plus the strip
-    # list; represent as target idx and r-1 strip idx pairs.
-    dec: dict[int, list] = {k: [] for k in range(K)}
-    for s in range(K):
-        for t, c in enumerate(per_s[s]):
-            occupied = [sl for sl in range(r)
-                        if int(plan.slot_pair[c, sl]) != P_pairs]
-            for sl in occupied:
-                p = int(plan.slot_pair[c, sl])
-                k = int(plan.pair_k[p])
-                strips = [(int(plan.pair_i[int(plan.slot_pair[c, sl2])]),
-                           int(plan.pair_j[int(plan.slot_pair[c, sl2])]))
-                          for sl2 in occupied if sl2 != sl]
-                tgt = (int(plan.pair_i[p]), int(plan.pair_j[p]))
-                dec[k].append((s, t, tgt, strips))
-    dwidth = max((len(d) for d in dec.values()), default=0)
-    dec_src = np.zeros((K, dwidth, 2), dtype=np.int32)       # (sender, slot)
-    dec_tgt = np.full((K, dwidth, 2), -1, dtype=np.int32)    # (i, j)
-    dec_strip = np.full((K, dwidth, r - 1, 2), -1, dtype=np.int32) \
-        if r > 1 else np.zeros((K, dwidth, 0, 2), np.int32)
-    for k, items in dec.items():
-        for t, (s, slot_t, (i, j), strips) in enumerate(items):
-            dec_src[k, t] = (s, slot_t)
-            dec_tgt[k, t] = (i, j)
-            for ri, (i2, j2) in enumerate(strips):
-                dec_strip[k, t, ri] = (i2, j2)
-    return enc_idx, dec_src, dec_tgt, dec_strip
-
-
-def _as_words(x):
-    return jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-
-
-def _as_floats(w):
-    return jax.lax.bitcast_convert_type(w, jnp.float32)
-
-
-def fused_exchange(values: jnp.ndarray, enc_idx, dec_src, dec_tgt, dec_strip,
-                   mesh: Mesh):
-    """One coded Shuffle as a single all_gather of packed XOR buffers.
-
-    values [n, n] float32 (replicated Map output; each server only reads its
-    own columns through the schedule indices). Returns [n, n] recovered
-    missing values (0 where not delivered) - identical on every server.
-    Validation reference only: the production path is `FusedSparseShuffle`.
-    """
-    def per_server(words, enc_s, dec_src_s, dec_tgt_s, dec_strip_s):
-        # enc_s [1, W, r, 2] on this shard.
-        enc_s = enc_s[0]
-        valid = enc_s[:, :, 0] >= 0
-        vals = words[jnp.clip(enc_s[:, :, 0], 0), jnp.clip(enc_s[:, :, 1], 0)]
-        buf = jnp.where(valid, vals, jnp.uint32(0))
-        coded = jax.lax.reduce(buf, jnp.uint32(0), jax.lax.bitwise_xor, (1,))
-        allbufs = jax.lax.all_gather(coded, "servers")       # [K, W]
-        # Decode this server's targets.
-        d_src, d_tgt, d_strip = dec_src_s[0], dec_tgt_s[0], dec_strip_s[0]
-        got = allbufs[d_src[:, 0], d_src[:, 1]]
-        sv = d_strip[:, :, 0] >= 0
-        strip_vals = words[jnp.clip(d_strip[:, :, 0], 0),
-                           jnp.clip(d_strip[:, :, 1], 0)]
-        strip = jax.lax.reduce(jnp.where(sv, strip_vals, jnp.uint32(0)),
-                               jnp.uint32(0), jax.lax.bitwise_xor, (1,))
-        rec = got ^ strip
-        out = jnp.zeros(words.shape, jnp.uint32)
-        tgt_ok = d_tgt[:, 0] >= 0
-        out = out.at[jnp.clip(d_tgt[:, 0], 0),
-                     jnp.clip(d_tgt[:, 1], 0)].set(
-            jnp.where(tgt_ok, rec, jnp.uint32(0)))
-        return jax.lax.psum(out, "servers")   # union of per-server recoveries
-
-    # The value words enter as a replicated argument, not a closure, and
-    # the call runs under the mesh: a mesh with explicit axes (the
-    # `jax.make_mesh` default) refuses arrays committed to one device.
-    f = jax.shard_map(per_server, mesh=mesh,
-                      in_specs=(P(),) + (P("servers"),) * 4, out_specs=P())
-    with jax.set_mesh(mesh):
-        out_words = jax.jit(f)(_as_words(values), jnp.asarray(enc_idx),
-                               jnp.asarray(dec_src), jnp.asarray(dec_tgt),
-                               jnp.asarray(dec_strip))
-    return _as_floats(out_words)
-
-
-def run_fused(g: Graph, values: np.ndarray, alloc: Allocation, mesh: Mesh):
-    """Convenience wrapper: schedule + dense exchange; returns [n, n]."""
-    sched = build_schedule(g, alloc)
-    return fused_exchange(jnp.asarray(values, jnp.float32), *sched, mesh=mesh)

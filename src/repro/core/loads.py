@@ -65,18 +65,14 @@ def empirical_loads(graph, alloc, *, topology=None) -> dict[str, float]:
     from .bitcodec import T_BITS
     from .graph_models import CSR, Graph
     from .shuffle_plan import (HierarchicalPlan, ShufflePlan,
-                               compile_hierarchical, compile_plan_csr)
+                               compile_hierarchical, compile_plan_csr,
+                               resolve_topology)
 
     hplan = None
     if isinstance(graph, HierarchicalPlan):
-        hplan = graph
-        if topology is not None and topology != hplan.topology:
-            raise ValueError(
-                f"topology {topology} disagrees with the plan's "
-                f"{hplan.topology}")
-        topology = hplan.topology
-        hplan.check_alloc(alloc)
-        plan = hplan.flat
+        hplan, topology = resolve_topology(graph, topology)
+        graph.check_alloc(alloc)
+        plan = graph.flat
     elif isinstance(graph, ShufflePlan):
         plan = graph
         plan.check_alloc(alloc)

@@ -1516,6 +1516,32 @@ class HierarchicalPlan:
                                  inter_bits + intra_bits, self.flat.n)
 
 
+def resolve_topology(plan, topology):
+    """The plan to run on `topology`, and the topology it runs on.
+
+    A `HierarchicalPlan` carries its own topology, which a `topology` given
+    beside it must equal; on a flat topology it degenerates to its flat
+    schedule. A flat `ShufflePlan` (or no plan yet) runs on a flat topology
+    or none. Returns ``(plan, topology)``: the `HierarchicalPlan` when the
+    topology is non-flat, else the flat plan (or None, unchanged).
+    """
+    if isinstance(plan, HierarchicalPlan):
+        if topology is not None and topology != plan.topology:
+            raise ValueError(
+                f"topology {topology} disagrees with the plan's "
+                f"{plan.topology}")
+        topology = plan.topology
+        if topology.is_flat:
+            plan = plan.flat
+    elif (plan is not None and topology is not None
+          and not topology.is_flat):
+        raise ValueError(
+            "a non-flat Topology needs a HierarchicalPlan "
+            "(core.shuffle_plan.compile_hierarchical), got a flat "
+            "ShufflePlan")
+    return plan, topology
+
+
 def _rack_first_mapper(alloc: Allocation, R: int, S: int):
     """Designated in-rack sources: ``first[rho, j]`` is the offset within
     rack rho of its lowest server Mapping vertex j (0 if none Mapped it -

@@ -23,10 +23,15 @@ import numpy as np
 import pytest
 
 from repro import graphs
-from repro.core.allocation import divisible_n, er_allocation
+from repro.core import algorithms as algo
+from repro.core import engine
+from repro.core.allocation import (bipartite_allocation, divisible_n,
+                                   er_allocation)
 from repro.core.fused_shuffle import (FusedSparseShuffle, gather_deliveries,
                                       partition_plan, receiver_spans)
-from repro.core.shuffle_plan import compile_hierarchical, compile_plan_csr
+from repro.core.graph_models import Graph
+from repro.core.shuffle_plan import (compile_hierarchical, compile_plan_csr,
+                                     resolve_topology)
 from repro.graphs import EdgeDelta
 from repro.launch.mesh import Topology
 
@@ -108,6 +113,11 @@ out["encode_xor_kernel"] = parity(g, alloc, algo.pagerank(), iters=1,
                                   encode="xor-kernel")
 out["encode_jnp"] = parity(g, alloc, algo.pagerank(), iters=1, encode="jnp")
 
+# A flat K = 6 exchange on a mesh of 6 of the 8 devices.
+n = divisible_n(60, 6, 2)
+out["flat_k6"] = parity(graphs.erdos_renyi(n, 0.25, seed=5),
+                        er_allocation(n, 6, 2), algo.pagerank())
+
 # B=8 payload columns, then a rebind onto a plan whose partition has other
 # padded shapes (a denser graph on the same allocation), at B=1 and B=8.
 def words_equal(plan, csr, fx, vals):
@@ -188,8 +198,8 @@ out["scale_peak_below_dense"] = bool(peak < n * n)    # any [n,n] f32 would trip
 # Shape guard: every partitioned table is [nnz]/[plan]-sized and the
 # per-device rows are 1/K slices (+ padding slack) - nothing O(n^2)-shaped.
 s = fx.sched
-arrays = [s.loc_e, s.enc_l, s.enc_shift, s.enc_mask, s.dec_s, s.dec_w,
-          s.dec_mask, s.dec_shift, s.strip_l, s.strip_shift, s.strip_mask]
+arrays = [s.enc_e, s.enc_shift, s.enc_mask, s.dec_s, s.dec_w,
+          s.dec_mask, s.dec_shift, s.strip_e, s.strip_shift, s.strip_mask]
 out["tables_not_dense"] = bool(all(a.size < n * n // 8 for a in arrays))
 C = int(plan.col_sender.size) + int(plan.left_k.size)
 out["per_device_loc"] = bool(s.Lmax <= 2 * r * nnz // K + 8)
@@ -227,6 +237,7 @@ def test_fused_sparse_word_parity_models_programs_and_spill():
     assert res["spill_has_leftovers"]          # the case really spills
     assert res["spill_pagerank"] and res["spill_sssp"]
     assert res["encode_xor_kernel"] and res["encode_jnp"]
+    assert res["flat_k6"]                      # K = 6 on 6 of 8 devices
     assert res["b8"]                           # batched payload, flat
     assert res["rebind_reshaped"]              # the rebind changes shapes
     assert res["rebind_b1"] and res["rebind_b8"]
@@ -249,30 +260,61 @@ def test_fused_engine_acceptance_and_beyond_dense_limit():
     assert res["tables_placed_per_device"]
 
 
-@pytest.mark.parametrize("r", [2, 3])
-def test_slot_order_entries_are_local_and_compose_the_local_tables(r):
+@pytest.mark.parametrize("case", ["r1", "r2", "r3", "spill"])
+def test_slot_entries_are_the_plans_csr_entries_of_mapped_values(case):
     """The flat exchange's host gather reads edge words through `enc_e` and
-    `strip_e`: each entry is the zero sentinel `nnz` or a CSR entry whose
-    column vertex the server Mapped, and each table is its local-index
-    table composed with `loc_e`, the sentinel carried through."""
-    K = 4
-    n = divisible_n(60, K, r)
-    g = graphs.erdos_renyi(n, 0.2, seed=4)
-    alloc = er_allocation(n, K, r)
-    s = partition_plan(compile_plan_csr(g.csr, alloc), g.csr, alloc)
+    `strip_e`. Each entry is the zero sentinel `nnz` exactly where its mask
+    is 0; every other entry is a CSR entry whose column vertex the server
+    Mapped, and it is the plan's `pair_e` / `left_e` of that slot, checked
+    here slot by slot against the plan's columns and deliveries. r = 1 has
+    no strip slots; the bipartite spill has unicast leftovers."""
+    if case == "spill":
+        g = graphs.random_bipartite(48, 24, 0.3, seed=5)
+        alloc = bipartite_allocation(48, 24, 6, 3)
+    else:
+        n = divisible_n(60, 4, int(case[1]))
+        g = graphs.erdos_renyi(n, 0.2, seed=4)
+        alloc = er_allocation(n, 4, int(case[1]))
+    K, r = alloc.K, alloc.r
+    plan = compile_plan_csr(g.csr, alloc)
+    assert (plan.left_k.size > 0) == (case == "spill")
+    t = plan.edge_tables(g.csr, alloc)
+    s = partition_plan(plan, g.csr, alloc)
     nnz = g.csr.nnz
-    loc_z = np.concatenate([s.loc_e, np.full((K, 1), nnz)], axis=1)
-    pairs = ((s.enc_e, s.enc_l, s.enc_mask),
-             (s.strip_e, s.strip_l, s.strip_mask))
-    for ent, local, mask in pairs:
-        assert ent.dtype == np.int32 and ent.shape == local.shape
-        assert ((ent == nnz) == (local == s.Lmax)).all()
-        assert (mask[ent == nnz] == 0).all()    # sentinel slots XOR away
-        assert (ent != nnz).any()
+    for ent, mask in ((s.enc_e, s.enc_mask), (s.strip_e, s.strip_mask)):
+        assert ent.dtype == np.int32
+        assert np.array_equal(ent == nnz, mask == 0)
         for k in range(K):
-            assert np.array_equal(ent[k], loc_z[k][local[k]])
             real = ent[k][ent[k] != nnz]
             assert alloc.map_sets[k, g.csr.indices[real]].all()
+    assert (s.enc_e != nnz).any()
+    assert ((s.strip_e != nnz).any() if r > 1 else s.strip_e.size == 0)
+
+    # Encode: sender k's coded columns in (group, rank) order, then the
+    # leftovers whose smallest Mapping server is k, in plan order.
+    Pn = plan.pair_k.size
+    for k in range(K):
+        cols = [c for c in range(plan.col_sender.size)
+                if plan.col_sender[c] == k]
+        cols.sort(key=lambda c: (plan.col_gm[c], plan.col_rank[c]))
+        want = [[t.pair_e[p] if p < Pn else nnz for p in plan.slot_pair[c]]
+                for c in cols]
+        want += [[t.left_e[m]] + [nnz] * (r - 1)
+                 for m in range(plan.left_k.size)
+                 if np.argmax(alloc.map_sets[:, plan.left_j[m]]) == k]
+        assert np.array_equal(s.enc_e[k, :len(want)], np.reshape(want, (-1, r)))
+        assert (s.enc_e[k, len(want):] == nnz).all()
+
+    # Strip: delivery m of receiver k, segment t, strips the other slots
+    # of the column that carries it, in slot order.
+    for p in range(Pn):
+        m = plan.pos_covered[p]
+        k = plan.all_k[m]
+        for seg in range(r):
+            c, own = plan.pair_col[p, seg], plan.pair_slot[p, seg]
+            want = [t.pair_e[q] if q < Pn else nnz
+                    for slot, q in enumerate(plan.slot_pair[c]) if slot != own]
+            assert list(s.strip_e[k, m - plan.ptr[k], seg]) == want
 
 
 def _plan_of(kind):
@@ -348,3 +390,26 @@ def test_bind_refuses_deliveries_not_grouped_by_receiver():
     bad = dataclasses.replace(plan, all_k=all_k)
     with pytest.raises(ValueError, match="not grouped by receiver"):
         object.__new__(FusedSparseShuffle)._bind(bad, csr, alloc, None)
+
+
+@pytest.mark.parametrize("case", ["topology_disagrees", "flat_plan"])
+def test_every_entry_point_refuses_a_plan_its_topology_cannot_run(case):
+    """One rule reconciles a plan with a `Topology`: a `HierarchicalPlan`
+    refuses a topology other than its own, and a non-flat topology
+    refuses a flat `ShufflePlan`. The engine, the partition and the
+    exchange's bind all apply it."""
+    plan, csr, alloc = _plan_of("hier")
+    if case == "topology_disagrees":
+        topo, match = Topology.flat(4), "disagrees with the plan's"
+    else:
+        plan, topo, match = plan.flat, Topology(2, 2), "needs a HierarchicalPlan"
+    with pytest.raises(ValueError, match=match):
+        resolve_topology(plan, topo)
+    with pytest.raises(ValueError, match=match):
+        partition_plan(plan, csr, alloc, topo)
+    with pytest.raises(ValueError, match=match):
+        object.__new__(FusedSparseShuffle)._bind(plan, csr, alloc, topo)
+    g = Graph(model="er", params={}, csr=csr)
+    with pytest.raises(ValueError, match=match):
+        engine.compile(algo.pagerank(), g, alloc, "coded", plan=plan,
+                       topology=topo)
