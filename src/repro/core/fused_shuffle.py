@@ -45,6 +45,10 @@ degenerate to the single-level exchange on `Topology.flat(K)`).
 from __future__ import annotations
 
 import dataclasses
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor, wait
+from functools import partial
 
 import numpy as np
 import jax
@@ -63,6 +67,50 @@ from .shuffle_plan import (HierarchicalPlan, PlanShuffleResult, ShufflePlan,
                            _rack_first_mapper, _run_ranks, resolve_topology)
 
 FULL_MASK = np.uint32(0xFFFFFFFF)
+
+# The flat operand's host gather (`FusedSparseShuffle._slot_words`) cuts each
+# server's row into slices of at least `_MIN_CHUNK` words and runs them on one
+# pool of threads shared by the process; `np.take` releases the GIL. Rows
+# shorter than a slice take one serial `np.take` per server.
+_MIN_CHUNK = 1 << 18
+_MAX_THREADS = 16
+_pool: ThreadPoolExecutor | None = None
+_pool_lock = threading.Lock()
+
+
+def _gather_threads() -> int:
+    """Threads the slot-order gather may use: the cores this process may
+    run on, at most `_MAX_THREADS`."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:                # no affinity call on this OS
+        cores = os.cpu_count() or 1
+    return max(1, min(cores, _MAX_THREADS))
+
+
+def _run_jobs(jobs: list, workers: int) -> None:
+    """Run every job: in this thread if `workers` is 1, else on the shared
+    gather pool (made on first use), waiting for all before raising the
+    first error, so no job still writes when the call returns."""
+    global _pool
+    if workers == 1:
+        for job in jobs:
+            job()
+        return
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(_gather_threads(),
+                                       thread_name_prefix="slot-gather")
+    futures = [_pool.submit(job) for job in jobs]
+    wait(futures)
+    for f in futures:
+        f.result()
+
+
+def _cuts(n: int, parts: int) -> np.ndarray:
+    """[parts + 1] bounds that cut range(n) into `parts` near-equal
+    contiguous slices."""
+    return np.arange(parts + 1) * n // parts
 
 
 def _sender_layout(plan: ShufflePlan) -> tuple[np.ndarray, np.ndarray]:
@@ -499,8 +547,8 @@ class FusedSparseShuffle:
     and `phase.codec`; device scopes `exchange.encode`, `exchange.gather`
     and `exchange.decode` (`jax.named_scope`, HLO metadata only); and the
     registry counters `exchange_calls_total`,
-    `exchange_host_copy_bytes_total`, `exchange_bits_useful_total` and
-    `exchange_bits_sent_total`.
+    `exchange_host_copy_bytes_total`, `exchange_bits_useful_total`,
+    `exchange_bits_sent_total` and `exchange_encode_parallel_total`.
 
     encode:
       "xor-ref"    - batched kernels/xor_code route, jnp oracle (default).
@@ -578,6 +626,15 @@ class FusedSparseShuffle:
                 [lanes_last(s.enc_e).reshape(s.K, -1),
                  lanes_last(s.strip_e).reshape(s.K, -1)],
                 axis=1).astype(np.intp)
+            # The gather's chunk plan: each row in `parts` slices of at
+            # least _MIN_CHUNK words, about one per thread at B=1, and the
+            # staging copy of the [nnz] words in as many slices as B=1 uses
+            # threads. A row shorter than one slice runs serially.
+            N, threads = self._slot_e.shape[1], _gather_threads()
+            parts = max(1, min(-(-threads // s.K), N // _MIN_CHUNK))
+            self._row_cuts = _cuts(N, parts)
+            self._threads = threads if N >= _MIN_CHUNK else 1
+            self._nnz_cuts = _cuts(self._nnz, self._workers(1))
         self._bufs = {}                       # host buffers per payload shape
 
     def _in_specs(self) -> tuple:
@@ -778,12 +835,14 @@ class FusedSparseShuffle:
         """Encode + exchange of `exchange_words`, left on the mesh.
 
         The host gathers each server's words into the operand
-        (`operand_shape`) and places it one server per device. Flat: one
+        (`operand_shape`) and places it one server per device. Flat: a
         `np.take` of the edge words through the slot-order CSR entries
         (`FusedSparseSchedule.enc_e`, `strip_e`) into a buffer reused per
-        payload shape, so the device program gathers only its deliveries
-        from the all_gathered buffers. Two levels: each server's local Map
-        words (`loc_e`), which phase A all_gathers within the rack.
+        payload shape, in row slices on a pool of threads once a row is
+        long enough (`_slot_words`), so the device program gathers only
+        its deliveries from the all_gathered buffers. Two levels: each
+        server's local Map words (`loc_e`), which phase A all_gathers
+        within the rack.
 
         Returns the per-server padded delivery words, sharded one server
         per device: [K, (B,) Dmax] flat, [R, S, (B,) Dmax] on two levels.
@@ -797,8 +856,10 @@ class FusedSparseShuffle:
         batched = ew.ndim == 2
         B = int(ew.shape[1]) if batched else 1
         words = s.K * B * (s.Lmax if self._hier else self._slot_e.shape[1])
+        workers = 1 if self._hier else self._workers(B)
         with tr.span("phase.encode", backend="fused", B=B,
-                     nnz=int(edge_words.shape[0]), words=words):
+                     nnz=int(edge_words.shape[0]), words=words,
+                     workers=workers):
             if self._hier:
                 # payload axis first, the sentinel zero word at index nnz
                 ew = np.concatenate([np.atleast_2d(ew.T),
@@ -810,7 +871,7 @@ class FusedSparseShuffle:
                 operand = loc.reshape(
                     self.operand_shape(B if batched else None))
             else:
-                operand = self._slot_words(ew)
+                operand = self._slot_words(ew, workers)
         bits = self._schedule_bits * B
         attrs = dict(backend="fused", bits=bits, B=B, K=s.K)
         if self._hier:
@@ -845,24 +906,49 @@ class FusedSparseShuffle:
         reg.counter("exchange_bits_sent_total",
                     "bits the chips put into the exchange's collectives, "
                     "each buffer once").inc(32 * self._sent_words * B)
+        reg.counter("exchange_encode_parallel_total",
+                    "flat exchanges whose slot-order gather ran on more "
+                    "than one thread").inc(int(workers > 1))
         return dev
 
-    def _slot_words(self, ew: np.ndarray) -> np.ndarray:
+    def _workers(self, B: int) -> int:
+        """Threads a flat gather of a B-column payload runs on: one job per
+        (server, column, row slice), at most `_threads`."""
+        return min(self._slot_e.shape[0] * B * (self._row_cuts.size - 1),
+                   self._threads)
+
+    def _slot_words(self, ew: np.ndarray, workers: int) -> np.ndarray:
         """The flat operand: edge words [nnz] or [nnz, B] -> [K, (B,) N]
-        slot words, CSR entry `nnz` reading the zero sentinel. Both buffers
-        are allocated on a payload shape's first call and reused, so calls
-        on one exchange must not overlap (sessions and the serving worker
-        call it from one thread)."""
+        slot words, CSR entry `nnz` reading the zero sentinel.
+
+        The staging copy into a [(B,) nnz+1] source, then one `np.take` per
+        (server, payload column, row slice of `_row_cuts`), each into a
+        C-contiguous slice of the operand, on `workers` threads. Every job
+        writes its own slice, so the words are those of one serial
+        `np.take` per server. Both buffers are allocated on a payload
+        shape's first call and reused, so calls on one exchange must not
+        overlap (sessions and the serving worker call it from one thread);
+        two exchanges write to their own buffers. The two-level operand's
+        gather (`exchange_device`) stays serial: no benchmark cell runs it.
+        """
         lead = ew.shape[1:]
         if lead not in self._bufs:
             src = np.zeros(lead + (self._nnz + 1,), np.uint32)  # [(B,) nnz+1]
             out = np.empty(self.operand_shape(*lead), np.uint32)
             self._bufs[lead] = (src, out)
         src, out = self._bufs[lead]
-        src[..., :-1] = ew.T                  # payload axis first
-        for k, idx in enumerate(self._slot_e):
-            # mode="clip" keeps `out=` unbuffered; every index is in range
-            np.take(src, idx, axis=-1, out=out[k], mode="clip")
+        c = self._nnz_cuts                    # payload axis first
+        _run_jobs([partial(np.copyto, src[..., a:b], ew[a:b].T)
+                   for a, b in zip(c[:-1], c[1:])], workers)
+        rows = src.reshape(-1, self._nnz + 1)          # [B or 1, nnz+1]
+        dst = out.reshape(out.shape[0], rows.shape[0], -1)  # [K, B or 1, N]
+        c = self._row_cuts
+        # mode="clip" keeps `out=` unbuffered; every index is in range
+        _run_jobs([partial(np.take, rows[j], idx[a:b], -1, dst[k, j, a:b],
+                           "clip")
+                   for k, idx in enumerate(self._slot_e)
+                   for j in range(rows.shape[0])
+                   for a, b in zip(c[:-1], c[1:])], workers)
         return out
 
     def execute(self, edge_vals: np.ndarray) -> PlanShuffleResult:

@@ -9,6 +9,9 @@ What the exchange reports about itself, flat and on `Topology(2, 2)`:
 * `exchange.first_call` marks exactly the calls whose shapes are new to the
   jitted exchange, across `rebind`;
 * the registry counters equal their formulas, at B=1 and B=8;
+* `phase.encode` names the threads its gather ran on (`workers`), and
+  `exchange_encode_parallel_total` counts the calls that took more than one,
+  on a small graph and with the gather's chunk threshold lowered;
 * the three device scopes reach the lowered exchange;
 * delivered words equal `ShufflePlan.execute_coded_sparse` at B=1, at B=8
   and after a rebind that reshapes the exchange;
@@ -37,12 +40,14 @@ from repro import graphs, obs
 from repro.core import algorithms as algo
 from repro.core import engine
 from repro.core.allocation import divisible_n, er_allocation
+from repro.core import fused_shuffle
 from repro.core.bitcodec import floats_to_words
 from repro.graphs import EdgeDelta
 from repro.launch.mesh import Topology
 
 COUNTERS = ("exchange_calls_total", "exchange_host_copy_bytes_total",
-            "exchange_bits_useful_total", "exchange_bits_sent_total")
+            "exchange_bits_useful_total", "exchange_bits_sent_total",
+            "exchange_encode_parallel_total")
 
 
 def counters(reg):
@@ -96,6 +101,9 @@ def report(topo):
         ex = sess.fused
         res = sess.run(2)
         out["tree"] = tr.tree()
+        out["encode_attrs"] = sorted(tr.find("phase.encode")[0].attrs)
+        out["encode_workers"] = [sp.attrs["workers"]
+                                 for sp in tr.find("phase.encode")]
         out["bitwise"] = bool(np.array_equal(floats_to_words(plain.state),
                                              floats_to_words(res.state)))
         out["bits_equal"] = bool(plain.shuffle_bits == res.shuffle_bits)
@@ -107,6 +115,8 @@ def report(topo):
         sess.with_program(algo.multi_sssp(list(range(8)))).run(1)
         out["after_b8"] = counters(reg)
         out["b8_first"] = len(tr.find("exchange.first_call"))
+        out["b8_workers"] = [sp.attrs["workers"]
+                             for sp in tr.find("phase.encode")]
 
         # rebind onto the same padded shapes: the compiled call is reused.
         tr.reset()
@@ -147,6 +157,26 @@ def report(topo):
             delivered_equal(sess, rng.random((g.csr.nnz, 8), np.float32)),
             delivered_equal(upd, rng.random(upd.g.csr.nnz, np.float32)),
             delivered_equal(upd, rng.random((upd.g.csr.nnz, 8), np.float32))]
+
+        # The gather's chunk threshold lowered below the rows of this graph
+        # (and 4 threads whatever the host has): a fresh session's gathers
+        # run on the pool, at B=1 and B=8.
+        low = (fused_shuffle._MIN_CHUNK, fused_shuffle._gather_threads)
+        fused_shuffle._MIN_CHUNK = 16
+        fused_shuffle._gather_threads = lambda: 4
+        try:
+            pooled = compile_session()
+            tr.reset()
+            before = counters(reg)
+            pooled.run(2)
+            pooled.with_program(algo.multi_sssp(list(range(8)))).run(1)
+            after = counters(reg)
+        finally:
+            fused_shuffle._MIN_CHUNK, fused_shuffle._gather_threads = low
+        out["pooled_workers"] = [sp.attrs["workers"]
+                                 for sp in tr.find("phase.encode")]
+        out["pooled_counts"] = [after[c] - before[c] for c in (
+            "exchange_calls_total", "exchange_encode_parallel_total")]
     finally:
         obs.set_tracer(prev_tr)
         obs.set_registry(prev_reg)
@@ -225,7 +255,8 @@ def _expected(d: dict, B: int) -> dict:
             "exchange_host_copy_bytes_total":
                 4 * B * d["K"] * (operand + d["Dmax"]),
             "exchange_bits_useful_total": B * d["schedule_bits"],
-            "exchange_bits_sent_total": 32 * B * words}
+            "exchange_bits_sent_total": 32 * B * words,
+            "exchange_encode_parallel_total": 0}    # rows below a chunk
 
 
 @pytest.mark.parametrize("topo", TOPOLOGIES)
@@ -239,6 +270,22 @@ def test_counters_equal_their_formulas_at_b1_and_b8(reports, topo):
     # the flat schedule's useful bits fit inside what the chips send
     sent = rep["after_2"]["exchange_bits_sent_total"]
     assert 0 < rep["after_2"]["exchange_bits_useful_total"] <= sent
+
+
+@pytest.mark.parametrize("topo", TOPOLOGIES)
+def test_encode_workers_and_the_parallel_counter(reports, topo):
+    """The test graph's rows are shorter than one chunk, so every gather is
+    serial. Under a lowered threshold the flat gather runs its K x B x
+    slices on 4 threads and every flat call counts as parallel; the
+    two-level gather has no pool."""
+    rep = reports[topo]
+    assert rep["encode_workers"] == [1, 1] and rep["b8_workers"] == [1]
+    if topo == "flat":
+        assert rep["pooled_workers"] == [4, 4, 4]
+        assert rep["pooled_counts"] == [3, 3]
+    else:
+        assert rep["pooled_workers"] == [1, 1, 1]
+        assert rep["pooled_counts"] == [3, 0]
 
 
 @pytest.mark.parametrize("topo", TOPOLOGIES)
@@ -258,3 +305,5 @@ def test_disabled_tracer_is_bitwise_the_traced_run(reports, topo):
     rep = reports[topo]
     assert rep["disabled_collected"] == 0
     assert rep["bitwise"] and rep["bits_equal"]
+    # what the traced run's encode span reports besides
+    assert rep["encode_attrs"] == ["B", "backend", "nnz", "words", "workers"]

@@ -18,6 +18,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -27,6 +28,7 @@ from repro.core import algorithms as algo
 from repro.core import engine
 from repro.core.allocation import (bipartite_allocation, divisible_n,
                                    er_allocation)
+from repro.core import fused_shuffle
 from repro.core.fused_shuffle import (FusedSparseShuffle, gather_deliveries,
                                       partition_plan, receiver_spans)
 from repro.core.graph_models import Graph
@@ -413,3 +415,136 @@ def test_every_entry_point_refuses_a_plan_its_topology_cannot_run(case):
     with pytest.raises(ValueError, match=match):
         engine.compile(algo.pagerank(), g, alloc, "coded", plan=plan,
                        topology=topo)
+
+
+SCRIPT_POOLED = PREAMBLE + r"""
+from repro.core import fused_shuffle
+
+# Chunk threshold lowered below the rows of these graphs and 6 threads,
+# whatever the host has, so every flat gather below runs on the pool.
+fused_shuffle._MIN_CHUNK = 8
+fused_shuffle._gather_threads = lambda: 6
+rng = np.random.default_rng(3)
+for r in (2, 3):
+    n = divisible_n(60, 4, r)
+    g = graphs.erdos_renyi(n, 0.2, seed=4)
+    alloc = er_allocation(n, 4, r)
+    plan = compile_plan_csr(g.csr, alloc)
+    fx = FusedSparseShuffle(plan, g.csr, alloc)
+    out[f"r{r}_slices"] = int(fx._row_cuts.size - 1)
+    for B in (None, 8):
+        vals = rng.random((g.csr.nnz,) + ((B,) if B else ()), np.float32)
+        ref = plan.execute_coded_sparse(vals, plan.edge_tables(g.csr, alloc))
+        out[f"r{r}_b{B or 1}"] = bool(np.array_equal(
+            floats_to_words(ref.values),
+            floats_to_words(fx.execute(vals).values)))
+
+g, alloc = case("er")
+plan = compile_plan_csr(g.csr, alloc)
+rn = engine.run(algo.pagerank(), g, alloc, 3, mode="coded", plan=plan,
+                path="sparse")
+rf = engine.run(algo.pagerank(), g, alloc, 3, mode="coded", plan=plan,
+                path="sparse", backend="fused")
+out["engine"] = bool(np.array_equal(floats_to_words(rn.state),
+                                    floats_to_words(rf.state)))
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def pooled():
+    return _run(SCRIPT_POOLED)
+
+
+@pytest.mark.parametrize("case", ["r2_b1", "r2_b8", "r3_b1", "r3_b8",
+                                  "engine"])
+def test_pooled_gather_delivers_the_plan_executors_words(pooled, case):
+    """With the chunk threshold lowered, a session's flat gather runs in
+    several row slices per server on the pool, and the delivered words (and
+    a 3-iteration PageRank) are bitwise the NumPy plan executor's."""
+    assert pooled["r2_slices"] > 1 and pooled["r3_slices"] > 1
+    assert pooled[case]
+
+
+@pytest.mark.parametrize("chunks", ["one", "several", "ragged"])
+@pytest.mark.parametrize("B", [None, 8])
+@pytest.mark.parametrize("r", [1, 2, 3])
+def test_pooled_slot_words_are_the_serial_take_bitwise(monkeypatch, r, B,
+                                                       chunks):
+    """`_slot_words` (no devices) against one serial `np.take` per server
+    through `_slot_e`, as the flat operand was first built: at the test
+    graph's own single chunk (serial), and with the chunk threshold lowered
+    to several slices per server that divide N, or that do not."""
+    n = divisible_n(60, 4, r)
+    g = graphs.erdos_renyi(n, 0.2, seed=4)
+    alloc = er_allocation(n, 4, r)
+    plan = compile_plan_csr(g.csr, alloc)
+    bind = (plan, g.csr, alloc, None)
+    ex = object.__new__(FusedSparseShuffle)
+    ex._bind(*bind)
+    K, N = ex._slot_e.shape
+    if chunks != "one":
+        start = 2 if chunks == "several" else 3
+        p = next(p for p in range(start, N) if (N % p == 0) == (chunks == "several"))
+        monkeypatch.setattr(fused_shuffle, "_MIN_CHUNK", 1)
+        monkeypatch.setattr(fused_shuffle, "_gather_threads", lambda: K * p)
+        ex._bind(*bind)
+        widths = np.diff(ex._row_cuts)
+        assert widths.size == p and widths.sum() == N
+        assert (widths.min() == widths.max()) == (chunks == "several")
+    workers = ex._workers(B or 1)
+    assert (workers == 1) == (chunks == "one")
+    rng = np.random.default_rng(r)
+    for _ in range(2):                    # the second call reuses the buffers
+        ew = rng.integers(0, 2**32, (g.csr.nnz,) + ((B,) if B else ()),
+                          dtype=np.uint32)
+        src = np.concatenate([np.atleast_2d(ew.T),
+                              np.zeros((B or 1, 1), np.uint32)], axis=1)
+        src = src[0] if B is None else src
+        want = np.stack([np.take(src, idx, axis=-1) for idx in ex._slot_e])
+        got = ex._slot_words(ew, workers)
+        assert got.shape == want.shape == ex.operand_shape(B)
+        assert np.array_equal(got, want)
+
+
+def test_two_exchanges_gather_on_the_shared_pool_at_once(monkeypatch):
+    """Calls on two exchanges may overlap: they share the gather pool but
+    write their own buffers. Two threads, each calling its own exchange
+    with more jobs than cores under a short switch interval, get the serial
+    words on every call."""
+    cores = os.cpu_count() or 1
+    monkeypatch.setattr(fused_shuffle, "_MIN_CHUNK", 1)
+    monkeypatch.setattr(fused_shuffle, "_gather_threads", lambda: 4 * cores)
+    exs = []
+    for r in (2, 3):
+        n = divisible_n(60, 4, r)
+        g = graphs.erdos_renyi(n, 0.2, seed=4)
+        alloc = er_allocation(n, 4, r)
+        ex = object.__new__(FusedSparseShuffle)
+        ex._bind(compile_plan_csr(g.csr, alloc), g.csr, alloc, None)
+        assert ex._workers(1) > cores
+        exs.append(ex)
+    bad = []
+
+    def loop(ex, seed):
+        rng = np.random.default_rng(seed)
+        for _ in range(100):
+            ew = rng.integers(0, 2**32, ex._nnz, dtype=np.uint32)
+            src = np.append(ew, np.uint32(0))
+            want = np.stack([np.take(src, idx) for idx in ex._slot_e])
+            if not np.array_equal(ex._slot_words(ew, ex._workers(1)), want):
+                bad.append(seed)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ts = [threading.Thread(target=loop, args=(ex, i))
+              for i, ex in enumerate(exs)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
+    assert bad == []
